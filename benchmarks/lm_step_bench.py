@@ -136,7 +136,7 @@ def moe_dispatch_rows() -> list[dict]:
     modeled a2a valid/wire bytes against the dense path's replication bytes
     (valid must be strictly below dense replication — the whole point of
     routing tokens instead of replicating the expert table)."""
-    from repro.core.compat import make_mesh
+    from repro.core import make_mesh
     from repro.models import ffn
     from repro.models.module import init_params
     from repro.models.sharding import make_recipe, use_recipe
@@ -194,7 +194,7 @@ def train_step_rows() -> list[dict]:
     reduce-scatters/all-gathers while the baseline makes no such claim."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from repro.core.compat import make_mesh
+    from repro.core import make_mesh
     from repro.launch import hlo_walk
     from repro.train.buckets import zero_comm_model
     from repro.train.optimizer import init_zero_opt_state

@@ -9,10 +9,18 @@ the LM-framework extensions.  Prints ``name,us_per_call,derived`` CSV blocks.
   * roofline_table  — §Roofline aggregation of the dry-run artifacts
 
 Usage: PYTHONPATH=src python -m benchmarks.run [--quick] [--skip gemm_layouts]
+
+Each section runs in a child process of its own and this parent never
+imports JAX: a device belongs to one process at a time, and a parent holding
+it would leave a section's child (``gemm_layouts`` starts one) without it.
 """
 import argparse
+import os
+import subprocess
 import sys
 import time
+
+ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 
 
 def main() -> None:
@@ -21,33 +29,36 @@ def main() -> None:
     ap.add_argument("--skip", action="append", default=[])
     args = ap.parse_args()
 
-    from benchmarks import feature_matrix, relayout_bench, lm_step_bench, roofline_table, gemm_layouts
+    datasets = ("MINI",) if args.quick else ("MINI", "EXTRALARGE")
+    # (skip key, title, module, call that returns the section's lines)
+    sections = [
+        ("feature_matrix", "feature_matrix (paper Table 1)", "feature_matrix", "run()"),
+        ("relayout_bench", "relayout_bench (paper §3.2)", "relayout_bench", "run()"),
+        ("gemm_layouts", "gemm_layouts (paper Fig. 3)", "gemm_layouts",
+         f"run(datasets={datasets!r})"),
+        ("lm_step_bench", "lm_step_bench (framework)", "lm_step_bench", "run()"),
+        ("roofline_table", "roofline_table singlepod (§Roofline)", "roofline_table",
+         "run('singlepod')"),
+        ("roofline_table", "roofline_table multipod (§Dry-run)", "roofline_table",
+         "run('multipod')"),
+    ]
 
-    sections = []
-    if "feature_matrix" not in args.skip:
-        sections.append(("feature_matrix (paper Table 1)", lambda: feature_matrix.run()))
-    if "relayout_bench" not in args.skip:
-        sections.append(("relayout_bench (paper §3.2)", lambda: relayout_bench.run()))
-    if "gemm_layouts" not in args.skip:
-        datasets = ("MINI",) if args.quick else ("MINI", "EXTRALARGE")
-        sections.append(("gemm_layouts (paper Fig. 3)", lambda: gemm_layouts.run(datasets=datasets)))
-    if "lm_step_bench" not in args.skip:
-        sections.append(("lm_step_bench (framework)", lambda: lm_step_bench.run()))
-    if "roofline_table" not in args.skip:
-        sections.append(("roofline_table singlepod (§Roofline)", lambda: roofline_table.run("singlepod")))
-        sections.append(("roofline_table multipod (§Dry-run)", lambda: roofline_table.run("multipod")))
-
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(ROOT, "src"), ROOT] + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
     failures = 0
-    for name, fn in sections:
-        print(f"\n=== {name} ===")
+    for key, title, module, call in sections:
+        if key in args.skip:
+            continue
+        print(f"\n=== {title} ===", flush=True)
         t0 = time.time()
-        try:
-            for line in fn():
-                print(line)
+        code = f"from benchmarks import {module}\nfor line in {module}.{call}:\n    print(line)"
+        proc = subprocess.run([sys.executable, "-c", code], cwd=ROOT, env=env)
+        if proc.returncode == 0:
             print(f"# section completed in {time.time()-t0:.1f}s")
-        except Exception as e:  # noqa: BLE001
+        else:
             failures += 1
-            print(f"# SECTION FAILED: {e!r}")
+            print(f"# SECTION FAILED: exit code {proc.returncode}")
     if failures:
         sys.exit(1)
 

@@ -37,13 +37,10 @@ automatically.  The per-rank compute is the layout-parametric GEMM kernel
 
 Run:  python examples/distributed_gemm.py --majors J/K/J --dataset MINI
       python examples/distributed_gemm.py --summa --grid 2x4
-(on CPU it fakes 8 devices; on a TPU slice it uses the real ones)
+(run as a script on the CPU it fakes 8 devices; on a TPU host it uses the
+real ones, and the SUMMA grid defaults to the squarest one they allow)
 """
 import os
-
-if "XLA_FLAGS" not in os.environ:
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-
 import argparse
 import functools
 import sys
@@ -211,6 +208,18 @@ def comm_volume_model(algo: str, *, ni: int, nj: int, nk: int,
     raise ValueError(f"unknown algo {algo!r}")
 
 
+def default_grid(n_devices: int) -> tuple[int, int]:
+    """The squarest (rows, cols) grid over ``n_devices``, rows <= cols."""
+    rows = max(r for r in range(1, int(n_devices ** 0.5) + 1) if n_devices % r == 0)
+    return rows, n_devices // rows
+
+
+def _tile_block(n: int, block: int = 256) -> int:
+    """Kernel block along a tile dim of extent ``n``: ``block`` where it
+    divides, else the whole dim (a whole dim is always a legal TPU block)."""
+    return block if n % block == 0 else n
+
+
 @functools.lru_cache(maxsize=64)  # reuse the jitted program across calls
 def summa_ring_program(*, ni: int, nj: int, nk: int, grid: tuple[int, int] = (2, 4),
                        majors: str = "I/I/K", mesh=None, double_buffer: bool = True):
@@ -254,6 +263,7 @@ def summa_ring_program(*, ni: int, nj: int, nk: int, grid: tuple[int, int] = (2,
     P_l = _mat_layout("i", "j", mi, nj, "i")  # partial panel, i-major internal
 
     local_majors = f"I/{a_major}/{b_major}"
+    blocks = dict(bm=_tile_block(mi), bn=_tile_block(jr), bk=_tile_block(kc))
 
     def ring_phase(a_data, b_data):
         A_dist = DistBag(a_data, A_tile, dtA, ("Ri", "Ck"))
@@ -265,7 +275,8 @@ def summa_ring_program(*, ni: int, nj: int, nk: int, grid: tuple[int, int] = (2,
                 # per-rank layout-parametric GEMM (paper's kernel, Pallas on
                 # TPU) accumulating into the rotating j-block of the panel
                 jb = (state["Ri"] + _s) % R
-                new = ops.gemm_panel(a.data, b_panel.data, p_.data, jb, majors=local_majors)
+                new = ops.gemm_panel(a.data, b_panel.data, p_.data, jb,
+                                     majors=local_majors, **blocks)
                 return p_.with_data(new)
 
             return rank_map(step, dtA, p, A_dist, b_cur, out_tile_layout=P_l)
@@ -527,24 +538,28 @@ def main():
     ap.add_argument("--majors", default=None, help="e.g. J/K/J; default: all 8")
     ap.add_argument("--ranks", type=int, default=None)
     ap.add_argument("--summa", action="store_true", help="2-D-grid SUMMA instead of 1-D")
-    ap.add_argument("--grid", default="2x4", help="SUMMA grid rows x cols")
+    ap.add_argument("--grid", default=None,
+                    help="SUMMA grid rows x cols (default: squarest over the devices)")
     ap.add_argument("--blocking", action="store_true",
                     help="SUMMA: blocking ring shifts instead of the double-buffered default")
     ap.add_argument("--uneven", action="store_true",
                     help="SUMMA: bump every dim by +1 so nothing divides the "
                          "grid and the ragged (v-collective) path runs")
     args = ap.parse_args()
+    # CPU bring-up: 8 fake host devices (the flag is read when the backend
+    # starts, below); a TPU host uses its own devices
+    os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+    grid = (tuple(int(x) for x in args.grid.split("x")) if args.grid
+            else default_grid(len(jax.devices())))
 
     ni, nj, nk = DATASETS[args.dataset]
     configs = [args.majors] if args.majors else LAYOUT_CONFIGS
     for majors in configs:
         if args.summa and args.uneven:
-            grid = tuple(int(x) for x in args.grid.split("x"))
             C, ref = run_ragged_summa_gemm(ni=ni + 1, nj=nj + 1, nk=nk + 1,
                                            majors=majors, grid=grid,
                                            double_buffer=not args.blocking, verbose=True)
         elif args.summa:
-            grid = tuple(int(x) for x in args.grid.split("x"))
             C, ref = run_summa_gemm(ni=ni, nj=nj, nk=nk, majors=majors, grid=grid,
                                     double_buffer=not args.blocking, verbose=True)
         else:

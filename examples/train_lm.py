@@ -62,12 +62,12 @@ mesh = None
 if args.zero:
     if args.devices < 2:
         ap.error("--zero needs --devices > 1 (a data-parallel mesh)")
-    from repro.core.compat import make_mesh
+    from repro.core import make_mesh
     mesh = make_mesh((args.devices,), ("data",))
     print(f"mesh {dict(mesh.shape)}, explicit ZeRO-2 step "
           f"(bucket threshold {args.bucket_kb} KiB)")
 elif args.devices > 1:
-    from repro.core.compat import make_mesh
+    from repro.core import make_mesh
     mesh = make_mesh((args.devices // 2, 2), ("data", "model"))
     recipe = make_recipe(CFG, mesh)
     print(f"mesh {dict(mesh.shape)}, attn_mode={recipe.attn_mode}, bindings={recipe.bindings}")

@@ -106,9 +106,9 @@ paths plug into the plans' compute slots (full table in
   carry-state flash kernel over the resident Q chunk vs the held KV block,
   threading unnormalized ``(acc, m, l)`` across hops (input/output aliased,
   so the chained result is bit-identical to the single-shot kernel at f32);
-* ``flash_decode`` — split-KV flash decoding over the serving engine's KV
-  cache: grid over cache blocks emitting per-block partials, LSE-combined
-  in an epilogue, masked by each slot's ``cache_len``/positions extents.
+* ``flash_decode`` — flash decoding over the serving engine's KV cache:
+  the cache blocks stream through an online softmax held in VMEM, masked by
+  each slot's ``cache_len`` and start position (scalar-prefetched extents).
 
 Defaults resolve per backend (TPU -> compiled Pallas, CPU -> jnp
 reference); ``impl="interpret"`` runs the same kernels through the Pallas
@@ -183,7 +183,6 @@ param prefetch          ``MPI_Iallgatherv`` (:func:`shard_all_gatherv_start`):
                         compute chain — the prefetch for the next forward
 ======================  =====================================================
 """
-from .compat import make_mesh, shard_map
 from .dims import LayoutError, ceil_div, common_refinement, ragged_split
 from .layout import (
     Axis,
@@ -214,7 +213,7 @@ from .traverser import hoist as hoist_trav
 from .traverser import set_length as set_length_trav
 from .relayout import RelayoutPlan, check_ragged_dims, relayout, relayout_plan, transfer_kind
 from .request import Pending, wait_all
-from .dist import DistTraverser, mpi_traverser, mpi_cart_traverser
+from .dist import DistTraverser, make_mesh, mpi_traverser, mpi_cart_traverser
 from .collectives import (
     DistBag,
     scatter,
@@ -302,7 +301,6 @@ __all__ = [
     "mpi_traverser",
     "mpi_cart_traverser",
     "make_mesh",
-    "shard_map",
     "scatter",
     "gather",
     "broadcast",

@@ -81,10 +81,10 @@ from typing import Any, Callable, Mapping, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .bag import Bag
-from .compat import shard_map
 from .dims import LayoutError, check_same_space, prod
 from .layout import Axis, Layout
 from .relayout import check_ragged_dims, relayout
@@ -1753,8 +1753,10 @@ def rank_map(
         out_arr = out.data if isinstance(out, Bag) else out
         return out_arr.reshape((1,) * lead + out_layout.shape)
 
+    # check_vma=False: the per-rank compute may hold Pallas kernels, which
+    # have no varying-axes rule
     mapped = shard_map(
-        shard_fn, mesh=dt.mesh, in_specs=in_specs, out_specs=out_spec
+        shard_fn, mesh=dt.mesh, in_specs=in_specs, out_specs=out_spec, check_vma=False
     )(*[db.data for db in dist_bags])
     return DistBag(mapped, out_layout, dt, rank_dims, extents=out_extents)
 

@@ -24,6 +24,7 @@ from .traverser import Traverser, set_length
 
 __all__ = [
     "DistTraverser",
+    "make_mesh",
     "mpi_traverser",
     "mpi_cart_traverser",
     "partition_spec",
@@ -31,6 +32,14 @@ __all__ = [
 ]
 
 MeshAxes = tuple[str, ...]
+
+
+def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], **kwargs) -> Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``.  JAX defaults mesh axes to
+    ``Explicit``, which breaks the ``shard_map``-based collectives and the
+    GSPMD recipes built on this mesh."""
+    auto = (jax.sharding.AxisType.Auto,) * len(tuple(axis_names))
+    return jax.make_mesh(axis_shapes, axis_names, axis_types=auto, **kwargs)
 
 
 def _as_axes(a) -> MeshAxes:

@@ -78,12 +78,8 @@ def _flash_kernel(
     def _init():
         if has_carry:
             acc_ref[...] = ci_acc[0, 0].astype(jnp.float32)
-            m_ref[...] = jnp.broadcast_to(
-                ci_m[0, 0].astype(jnp.float32)[:, None], m_ref.shape
-            )
-            l_ref[...] = jnp.broadcast_to(
-                ci_l[0, 0].astype(jnp.float32)[:, None], l_ref.shape
-            )
+            m_ref[...] = ci_m[0, 0]
+            l_ref[...] = ci_l[0, 0]
         else:
             acc_ref[...] = jnp.zeros_like(acc_ref)
             m_ref[...] = jnp.full_like(m_ref, NEG_INF)
@@ -130,8 +126,8 @@ def _flash_kernel(
     def _store():
         if emit_state:
             o_acc[0, 0] = acc_ref[...]
-            o_m[0, 0] = m_ref[:, 0]
-            o_l[0, 0] = l_ref[:, 0]
+            o_m[0, 0] = m_ref[...]
+            o_l[0, 0] = l_ref[...]
         else:
             l = l_ref[:, 0]
             l = jnp.where(l == 0.0, 1.0, l)  # guard fully-masked rows
@@ -152,14 +148,16 @@ def _pad_dim(x, axis: int, to: int):
 
 def _specs(bq: int, bk: int, D: int, Dv: int, group: int):
     """BlockSpecs shared by both entry points (index maps take the
-    scalar-prefetch ref as a trailing arg and ignore it)."""
+    scalar-prefetch ref as a trailing arg and ignore it).  The ``m``/``l``
+    carry travels lane-broadcast as (B, Hq, Sq, 128), the layout of its VMEM
+    scratch, so its blocks are whole (bq, 128) tiles."""
     q_spec = pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j, off: (b, h, i, 0))
     k_spec = pl.BlockSpec((1, 1, bk, D),
                           lambda b, h, i, j, off, group=group: (b, h // group, j, 0))
     v_spec = pl.BlockSpec((1, 1, bk, Dv),
                           lambda b, h, i, j, off, group=group: (b, h // group, j, 0))
     acc_spec = pl.BlockSpec((1, 1, bq, Dv), lambda b, h, i, j, off: (b, h, i, 0))
-    ml_spec = pl.BlockSpec((1, 1, bq), lambda b, h, i, j, off: (b, h, i))
+    ml_spec = pl.BlockSpec((1, 1, bq, 128), lambda b, h, i, j, off: (b, h, i, 0))
     return q_spec, k_spec, v_spec, acc_spec, ml_spec
 
 
@@ -276,6 +274,8 @@ def flash_attention_carry_pallas(
         pad_rows = jnp.arange(Sq_p) >= Sq
         m = jnp.where(pad_rows[None, None], NEG_INF, m)
     l = _pad_dim(l.astype(jnp.float32), 2, Sq_p)
+    m = jnp.broadcast_to(m[..., None], m.shape + (128,))
+    l = jnp.broadcast_to(l[..., None], l.shape + (128,))
     k = _pad_dim(k, 2, Skv_p)
     v = _pad_dim(v, 2, Skv_p)
     nkv = Skv_p // bk_
@@ -305,13 +305,14 @@ def flash_attention_carry_pallas(
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, Hq, Sq_p, Dv), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hq, Sq_p), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hq, Sq_p), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hq, Sq_p, 128), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hq, Sq_p, 128), jnp.float32),
         ],
         # flat operands: offs, q, k, v, acc, m, l — carry updates in place
         input_output_aliases={4: 0, 5: 1, 6: 2},
         interpret=interpret,
     )(offs, q, k, v, acc, m, l)
+    m_o, l_o = m_o[..., 0], l_o[..., 0]
     if Sq_p != Sq:
         acc_o, m_o, l_o = acc_o[:, :, :Sq], m_o[:, :, :Sq], l_o[:, :, :Sq]
     return acc_o, m_o, l_o

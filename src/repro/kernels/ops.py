@@ -39,7 +39,12 @@ def default_impl() -> str:
 
 
 def _resolve(impl: str | None) -> str:
-    return impl or default_impl()
+    impl = impl or default_impl()
+    if impl == "interpret" and jax.default_backend() == "tpu":
+        # interpret mode is the CPU oracle of the kernels; on the chip it
+        # would run the kernel body as plain XLA ops and hide the kernel
+        raise ValueError("impl='interpret' is not allowed on a TPU backend")
+    return impl
 
 
 def gemm(a, b, acc=None, *, majors: str = "I/I/K", impl: str | None = None, **kw):
@@ -143,15 +148,18 @@ def flash_attention_carry(q, k, v, carry=None, *, q_offset=0, k_offset=0,
 
 def flash_decode(q, k_cache, v_cache, cache_len, *, q_positions=None,
                  impl: str | None = None, **kw):
-    """Split-KV decode attention over the cache (LSE-combined partials)."""
+    """Flash-decoding attention over the cache.  ``q_positions`` (B, S) are
+    the queries' absolute positions; every caller builds them as a row's
+    start plus ``arange(S)``, and the kernel takes only the start."""
     impl = _resolve(impl)
     if impl == "ref":
         return _ref.decode_attention_ref(
             q, k_cache, v_cache, cache_len, q_positions=q_positions,
             scale=kw.get("scale"),
         )
+    q_start = None if q_positions is None else q_positions[:, 0]
     return flash_decode_pallas(
-        q, k_cache, v_cache, cache_len, q_positions=q_positions,
+        q, k_cache, v_cache, cache_len, q_start=q_start,
         interpret=(impl == "interpret"), **kw,
     )
 

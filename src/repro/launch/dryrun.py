@@ -1,9 +1,13 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=512 "
+                           "--xla_disable_hlo_passes=cpu-all-reduce-combiner")
 
-# NOTE: the two lines above MUST run before any other import (jax locks the
+# NOTE: the lines above MUST run before any other import (jax locks the
 # device count at first init), which is why the docstring follows them and no
-# `from __future__` import is used in this module.
+# `from __future__` import is used in this module.  The gates classify the
+# program's own comm schedule from the CPU compile; the CPU backend's
+# all-reduce combiner would merge independent reductions (e.g. of different
+# decode microbatches) into one tuple all-reduce and so tie their chains.
 
 DOC = """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
 production mesh and record memory/cost/collective evidence.
@@ -319,7 +323,7 @@ def sp_ring_dryrun(*, batch: int = 2, seq: int = 256, d_model: int = 64,
     from repro.launch import hlo_walk
     from repro.models import attention as attn
     from repro.models.sharding import make_recipe, ragged_seq_extents, use_recipe
-    from repro.core.compat import make_mesh
+    from repro.core import make_mesh
 
     cfg = SimpleNamespace(n_heads=n_heads, n_kv=n_kv, head_dim=head_dim,
                           d_model=d_model, d_ff=4 * d_model,
@@ -401,12 +405,12 @@ def serve_dryrun(*, arch: str = "phi4-mini-3.8b", slots: int = 8,
     proving the gate measures the schedule, not walker blindness.
 
     ``attn_impl="interpret"`` routes each microbatch's attention through the
-    split-KV flash-decoding Pallas kernel in interpret mode, proving the
+    flash-decoding Pallas kernel in interpret mode, proving the
     staggered schedule still serializes nothing with the kernel in the
     traced program (the kernel is microbatch ``s``'s compute — the sibling
     that hides microbatch ``s-1``'s Iallreduce).
     """
-    from repro.core.compat import make_mesh
+    from repro.core import make_mesh
     from repro.launch import hlo_walk
     from repro.serve.tp_decode import DECODE_TP_PLAN_INTENT, make_tp_decode_step
 
@@ -469,7 +473,7 @@ def moe_dryrun(*, batch: int = 4, seq: int = 8, d_model: int = 64,
     """
     from types import SimpleNamespace
 
-    from repro.core.compat import make_mesh
+    from repro.core import make_mesh
     from repro.launch import hlo_walk
     from repro.models import ffn
     from repro.models.sharding import (make_recipe, ragged_expert_extents,
@@ -582,7 +586,7 @@ def train_dryrun(*, arch: str = "phi4-mini-3.8b", ranks: int = 8,
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.configs.base import ShapeCell
-    from repro.core.compat import make_mesh
+    from repro.core import make_mesh
     from repro.launch import hlo_walk
     from repro.train.buckets import zero_comm_model
     from repro.train.optimizer import init_zero_opt_state
@@ -858,7 +862,7 @@ def main() -> None:
     ap.add_argument("--attn-impl", default=None, choices=["jnp", "interpret"],
                     help="attention kernel impl for the --sp-ring/--serve "
                          "gates: 'interpret' traces the Pallas kernels "
-                         "(carry-state flash ring step / split-KV decode) in "
+                         "(carry-state flash ring step / flash decode) in "
                          "interpret mode so the 0-serialized verdict is "
                          "proven with the kernels in the program; default "
                          "keeps the jnp bodies")

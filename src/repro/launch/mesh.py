@@ -7,7 +7,7 @@ from __future__ import annotations
 
 import jax
 
-from repro.core.compat import make_mesh
+from repro.core import make_mesh
 
 __all__ = ["make_production_mesh", "make_local_mesh"]
 

@@ -50,9 +50,11 @@ def main() -> None:
     import numpy as np
 
     from repro import configs
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import lm
     from repro.serve.engine import Engine, ServeConfig
 
+    enable_compile_cache()
     cfg = configs.get(args.arch, smoke=args.smoke)
     params = lm.init_model(cfg, jax.random.PRNGKey(0))
     if args.ckpt_dir:
@@ -69,7 +71,7 @@ def main() -> None:
     mesh = None
     microbatches = 0
     if args.grid:
-        from repro.core.compat import make_mesh
+        from repro.core import make_mesh
 
         grid = tuple(int(x) for x in args.grid.split("x"))
         mesh = make_mesh(grid, ("data", "model"))

@@ -96,12 +96,14 @@ def train(args) -> int:
     from repro.configs.base import ShapeCell
     from repro.ckpt.manager import CheckpointManager
     from repro.data.pipeline import DataConfig, make_batch
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_local_mesh
     from repro.models import lm
     from repro.models.sharding import make_recipe, batch_shardings
     from repro.train.optimizer import OptConfig, init_opt_state
     from repro.train.trainer import make_train_step
 
+    enable_compile_cache()
     cfg = configs.get(args.arch, smoke=args.smoke)
     cell = ShapeCell("train", seq_len=args.seq_len, global_batch=args.global_batch, kind="train")
     dcfg = DataConfig(source=args.data, path=args.data_path)

@@ -16,8 +16,8 @@ ring step   ``flash_attention_carry_pallas`` jnp online-softmax merge
             KV block, ``(acc, m, l)`` carry  and interpret-mode oracle)
             threaded across ring steps
 decode      ``flash_decode_pallas``          jnp dense streaming attention
-(serving)   (split-KV grid + log-sum-exp     with pinned probability
-            combine epilogue)                rounding (bitwise oracle)
+(serving)   (KV-block grid, online softmax   with pinned probability
+            in VMEM, per-slot masks)         rounding (bitwise oracle)
 ==========  ===============================  ================================
 
 Overrides: ``attn_impl=`` on the model-facing ops (and ``impl=`` on
@@ -49,7 +49,6 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.compat import shard_map
 from repro.core.p2p import shard_ring_shift_start
 from repro.core.plan import intent_of, ring
 from repro.kernels import ops
@@ -295,10 +294,10 @@ def ring_attention_seq(q, k, v, *, mesh, axis_name: str = "model", q_spec=None,
                                      causal=causal, double_buffer=double_buffer,
                                      valid_len=valid_len, impl=impl, block=block)
 
-    # check_rep=False: pallas_call has no replication rule (harmless here —
+    # check_vma=False: pallas_call has no varying-axes rule (harmless here —
     # every output is plainly seq-sharded like q)
-    out = shard_map(body, mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec),
-                    out_specs=q_spec, check_rep=False)(q, k, v)
+    out = jax.shard_map(body, mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec),
+                        out_specs=q_spec, check_vma=False)(q, k, v)
     # ``slice_output=False`` hands the padded (B,H,R*cap,D) output back to the
     # caller so the pad slice can ride *through* the per-position output
     # projection and land terminal (nothing downstream), instead of sitting
@@ -334,9 +333,9 @@ def attention_decode(q, k_cache, v_cache, cache_len, *, q_positions=None,
     reductions over a sharded cache-seq dim become the distributed
     flash-decoding merge under GSPMD above whichever local impl ran.
     ``impl`` dispatch (see the module docstring's table): ``"pallas"`` /
-    ``"interpret"`` run the split-KV Pallas kernel
+    ``"interpret"`` run the flash-decoding Pallas kernel
     (:func:`repro.kernels.flash_decode.flash_decode_pallas`, KV-block grid +
-    log-sum-exp combine) with the output pinned at the activation-dtype
+    online softmax) with the output pinned at the activation-dtype
     boundary; ``"jnp"``/``"ref"`` (the non-TPU default) keep the dense jnp
     path below, whose pinned probability rounding is the serving oracle.
     """

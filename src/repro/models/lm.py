@@ -314,7 +314,8 @@ def decode_step(params, state: DecodeState, batch, cfg, *, new_counts=None,
       * ``prefill=True`` marks a whole-prompt chunk whose active rows start
         at position 0 (admission-time batched prefill); under an ``sp_ring``
         recipe the attention families run the chunk through the
-        sequence-parallel ring plan.
+        sequence-parallel ring plan.  A prefill step returns (B, 1, vocab)
+        logits: those of each row's last valid token.
     Rows may leave garbage *beyond* their valid count inside the cache
     capacity — sound for non-windowed caches because the next write starts
     at ``length + count`` and the attention mask never reads past ``length``.
@@ -403,6 +404,11 @@ def decode_step(params, state: DecodeState, batch, cfg, *, new_counts=None,
     else:
         raise ValueError(fam)
 
+    if prefill:
+        # only each row's last valid token predicts the next one: project that
+        # row alone (the full (B, S, vocab) tensor is GBs at serving widths)
+        last = jnp.maximum(jnp.broadcast_to(adv, positions.shape) - 1, 0)
+        x = x[jnp.arange(x.shape[0]), last][:, None]
     logits = lm_logits(params, x, cfg)
     return logits, DecodeState(caches=new_caches, positions=positions + adv)
 

@@ -156,7 +156,11 @@ class Engine:
                 return lm.decode_step(params, state, batch, cfg,
                                       new_counts=counts, prefill=prefill)
 
-        self._prefill_fn = jax.jit(lambda p, s, b, c: gspmd_step(p, s, b, c, prefill=True))
+        # the compiled step programs: (params, state, batch, counts) ->
+        # (logits, state); the state is donated, so the KV cache is updated
+        # in place instead of copied every step
+        self.prefill_fn = jax.jit(
+            lambda p, s, b, c: gspmd_step(p, s, b, c, prefill=True), donate_argnums=1)
         if mesh is not None and microbatches:
             from repro.serve.tp_decode import make_tp_decode_step
 
@@ -167,9 +171,10 @@ class Engine:
             # distributed math whose FP reduction order diverges from the
             # single-host oracle's; uncommitted inputs keep prefill bitwise
             # the oracle and let the shard_map reshard at the decode boundary
-            self._decode_fn = jax.jit(lambda p, s, b, c: tp(p, s, b, c > 0))
+            self.decode_fn = jax.jit(lambda p, s, b, c: tp(p, s, b, c > 0),
+                                     donate_argnums=1)
         else:
-            self._decode_fn = jax.jit(gspmd_step)
+            self.decode_fn = jax.jit(gspmd_step, donate_argnums=1)
 
     # ------------------------------------------------------------ public ----
     def submit(self, request_id: int, prompt: list[int] | None = None,
@@ -264,8 +269,8 @@ class Engine:
                 counts[i] = len(feed)
             batch = ({"embeds": jnp.asarray(buf)} if self._embeds_in
                      else {"tokens": jnp.asarray(buf)})
-            _, self.state = self._prefill_fn(self.params, self.state, batch,
-                                             jnp.asarray(counts))
+            _, self.state = self.prefill_fn(self.params, self.state, batch,
+                                            jnp.asarray(counts))
             for i, feed in feeds:
                 self.ledger.advance(i, len(feed))
             return
@@ -282,8 +287,8 @@ class Engine:
                     self.ledger.advance(i, 1)
             batch = ({"embeds": jnp.asarray(buf)} if self._embeds_in
                      else {"tokens": jnp.asarray(buf)})
-            _, self.state = self._prefill_fn(self.params, self.state, batch,
-                                             jnp.asarray(counts))
+            _, self.state = self.prefill_fn(self.params, self.state, batch,
+                                            jnp.asarray(counts))
 
     # ------------------------------------------------------------- decode ----
     def _decode_once(self) -> None:
@@ -304,8 +309,8 @@ class Engine:
                 buf[i, 0] = slot.tokens[-1]
         batch = ({"embeds": jnp.asarray(buf)} if self._embeds_in
                  else {"tokens": jnp.asarray(buf)})
-        logits, self.state = self._decode_fn(self.params, self.state, batch,
-                                             jnp.asarray(counts))
+        logits, self.state = self.decode_fn(self.params, self.state, batch,
+                                            jnp.asarray(counts))
         logits = np.asarray(logits[:, -1, : self.cfg.vocab])  # strip padded vocab
         for i, slot in enumerate(self.slots):
             if slot.request_id is None:

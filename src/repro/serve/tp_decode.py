@@ -33,7 +33,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core.compat import shard_map
 from repro.core.p2p import shard_all_gather_start, shard_all_reduce_start
 from repro.core.plan import intent_of, stagger
 from repro.models import lm
@@ -118,7 +117,7 @@ def make_tp_decode_step(cfg, mesh, *, slots: int, microbatches: int = 2,
 
     ``attn_impl`` picks the per-layer attention kernel under the stagger
     plan (see ``models/attention.py``'s dispatch table): ``"pallas"`` /
-    ``"interpret"`` run the split-KV flash-decoding kernel inside each
+    ``"interpret"`` run the flash-decoding kernel inside each
     microbatch's compute stage, ``None`` resolves per backend, ``"jnp"``
     keeps the dense pinned jnp path (the token-equality oracle's form).
     """
@@ -299,11 +298,11 @@ def make_tp_decode_step(cfg, mesh, *, slots: int, microbatches: int = 2,
         return logits, new_k, new_v, new_len, positions + counts
 
     out_specs = (P("data", None, None), kv_spec, kv_spec, len_spec, P("data"))
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(p_specs, kv_spec, kv_spec, len_spec, P("data"), in_batch, P("data")),
         out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )
 
     def step(params, state, batch, active):

@@ -253,18 +253,16 @@ def make_zero_train_step(cfg, mesh, ocfg: OptConfig, *, microbatches: int = 1,
                 tuple(new_err) if compress else (), out_metrics)
 
     def train_step(params, opt_state: OptState, batch):
-        from repro.core.compat import shard_map
-
         rep = lambda tree: jax.tree.map(lambda _: P(), tree)
         flat_spec = tuple(P("data") for _ in buckets)
         err_spec = flat_spec if compress else ()
         batch_spec = jax.tree.map(lambda _: P("data"), batch)
         # P() is a pytree-prefix spec for the replicated metrics dict
-        fn = shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh,
             in_specs=(rep(params), P(), flat_spec, flat_spec, err_spec, batch_spec),
             out_specs=(rep(params), P(), flat_spec, flat_spec, err_spec, P()),
-            check_rep=False,
+            check_vma=False,
         )
         new_params, step, mu, nu, err, metrics = fn(
             params, opt_state.step, opt_state.mu, opt_state.nu,

@@ -65,7 +65,7 @@ def distributed(compile_cache_dir):
 
 @functools.lru_cache(maxsize=None)
 def _mesh_cached(axis_shapes: tuple, axis_names: tuple):
-    from repro.core.compat import make_mesh
+    from repro.core import make_mesh
 
     return make_mesh(axis_shapes, axis_names)
 

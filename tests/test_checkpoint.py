@@ -88,7 +88,7 @@ cfg = configs.get('phi4-mini-3.8b', smoke=True)
 params = lm.init_model(cfg, jax.random.PRNGKey(0))
 specs = lm.build_specs(cfg)
 
-from repro.core.compat import make_mesh
+from repro.core import make_mesh
 mesh_a = make_mesh((4, 2), ('data', 'model'))
 recipe_a = make_recipe(cfg, mesh_a)
 params_a = jax.tree.map(lambda x, s: jax.device_put(x, s), params, recipe_a.param_shardings(specs))

@@ -35,7 +35,7 @@ for t in toks:
     ref_logits.append(np.asarray(lg, np.float32))
 
 # --- 4x2 mesh, cache sharded per the recipe ---
-from repro.core.compat import make_mesh
+from repro.core import make_mesh
 mesh = make_mesh((4, 2), ('data', 'model'))
 recipe = make_recipe(cfg, mesh)
 assert recipe.attn_mode in ('tp', 'sp')

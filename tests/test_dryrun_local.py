@@ -18,7 +18,7 @@ from repro.launch import hlo_walk
 
 cfg = configs.get('phi4-mini-3.8b', smoke=True)
 cell = ShapeCell('t', seq_len=128, global_batch=8, kind='train')
-from repro.core.compat import make_mesh
+from repro.core import make_mesh
 mesh = make_mesh((4, 2), ('data', 'model'))
 recipe = make_recipe(cfg, mesh)
 specs = lm.build_specs(cfg)
@@ -110,8 +110,9 @@ def test_pipeline_ring_classified_serialized(distributed):
     out = distributed(
         """
 import jax, jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-from repro.core.compat import make_mesh, shard_map
+from repro.core import make_mesh
 from repro.launch import hlo_walk
 
 mesh = make_mesh((8,), ('r',))

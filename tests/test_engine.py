@@ -129,7 +129,7 @@ def test_distributed_engine_matches_oracle(distributed):
         """
 import jax
 from repro import configs
-from repro.core.compat import make_mesh
+from repro.core import make_mesh
 from repro.models import lm
 from repro.serve.engine import Engine, ServeConfig
 
@@ -167,7 +167,7 @@ def test_distributed_engine_biased_qkv_matches_oracle(distributed):
         """
 import jax
 from repro import configs
-from repro.core.compat import make_mesh
+from repro.core import make_mesh
 from repro.models import lm
 from repro.serve.engine import Engine, ServeConfig
 

@@ -296,11 +296,11 @@ def test_flash_carry_ragged_q_chunk():
                                    rtol=2e-4, atol=2e-4, err_msg=name)
 
 
-# ----------------------------------------------- split-KV flash decode -------
+# ------------------------------------------------------ flash decode -------
 
 @pytest.mark.parametrize("hq,hkv", [(4, 4), (4, 2), (8, 1)])
 def test_flash_decode_gqa(hq, hkv):
-    """Split-KV decode vs the dense oracle: per-row cache lengths, GQA group
+    """Flash decode vs the dense oracle: per-row cache lengths, GQA group
     stacking, T % bk != 0 (padded tail masked)."""
     B, T, D = 3, 96, 16
     q = jnp.asarray(RNG.standard_normal((B, hq, 1, D)), jnp.float32)
@@ -324,6 +324,24 @@ def test_flash_decode_chunk_positions():
     pos = jnp.asarray([[10, 11, 12, 13], [0, 1, 2, 3]], jnp.int32)
     clen = jnp.asarray([14, 4], jnp.int32)
     out = ops.flash_decode(q, kc, vc, clen, q_positions=pos, impl="interpret", bk=32)
+    expect = ref.decode_attention_ref(q, kc, vc, clen, q_positions=pos)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
+                               rtol=2e-4, atol=2e-4)
+
+
+def test_flash_decode_chunk_row_blocks():
+    """A prefill chunk longer than the query block: rows split into bq-row
+    blocks (S % bq != 0 pads the last one), each head looped inside the
+    program, per-row causality from the row's start position."""
+    B, H, G, S, T, D = 2, 6, 2, 40, 96, 16
+    q = jnp.asarray(RNG.standard_normal((B, H, S, D)), jnp.float32)
+    kc = jnp.asarray(RNG.standard_normal((B, G, T, D)), jnp.float32)
+    vc = jnp.asarray(RNG.standard_normal((B, G, T, D)), jnp.float32)
+    start = np.array([0, 37], np.int32)
+    pos = jnp.asarray(start[:, None] + np.arange(S)[None, :], jnp.int32)
+    clen = jnp.asarray(start + S, jnp.int32)
+    out = ops.flash_decode(q, kc, vc, clen, q_positions=pos, impl="interpret",
+                           bq=16, bk=32)
     expect = ref.decode_attention_ref(q, kc, vc, clen, q_positions=pos)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                rtol=2e-4, atol=2e-4)

@@ -123,7 +123,7 @@ def test_moe_expert_parallel_matches_dense_oracle(distributed):
 import warnings
 import numpy as np, jax, jax.numpy as jnp
 from repro import configs
-from repro.core.compat import make_mesh
+from repro.core import make_mesh
 from repro.models import ffn
 from repro.models.module import init_params
 from repro.models.sharding import make_recipe, use_recipe
@@ -161,10 +161,12 @@ ys, _ = jax.jit(lambda xv: ep(xv, cts=skew))(x)
 assert np.isfinite(np.asarray(ys)).all()
 
 # dispatch='ep' without an active recipe falls back, loudly, to the oracle
+# (jitted like the oracle: an eager call may round differently from the jit)
 with warnings.catch_warnings(record=True) as w:
     warnings.simplefilter('always')
-    yf, _ = ffn.moe_ffn(p, x, n_experts=E, top_k=k,
-                        capacity_factor=float(E) / k, dispatch='ep')
+    yf, _ = jax.jit(lambda xv: ffn.moe_ffn(p, xv, n_experts=E, top_k=k,
+                                           capacity_factor=float(E) / k,
+                                           dispatch='ep'))(x)
 assert any('falling back' in str(x.message) for x in w)
 assert np.array_equal(np.asarray(yf), np.asarray(yd))
 print('OK')

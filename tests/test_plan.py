@@ -142,6 +142,7 @@ def test_plan_vs_hlo_agreement(distributed):
         """
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 from repro.core import *
 from repro.core.p2p import shard_ring_shift_start
 from repro.core.plan import intent_of, pipeline, ring

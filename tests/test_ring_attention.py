@@ -19,7 +19,7 @@ def test_ring_attention_matches_reference_and_variants_bitwise(distributed):
     out = distributed(
         """
 import numpy as np, jax, jax.numpy as jnp
-from repro.core.compat import make_mesh
+from repro.core import make_mesh
 from repro.models import attention as attn
 
 mesh = make_mesh((2, 4), ('data', 'model'))
@@ -63,7 +63,7 @@ def test_ring_attention_ragged_seq_shards(distributed):
     out = distributed(
         """
 import numpy as np, jax, jax.numpy as jnp
-from repro.core.compat import make_mesh
+from repro.core import make_mesh
 from repro.kernels.ref import attention_ref
 from repro.models import attention as attn
 from repro.models.sharding import ragged_seq_extents
@@ -104,7 +104,7 @@ def test_gqa_attention_sp_ring_recipe_ragged_seq(distributed):
         """
 import numpy as np, jax, jax.numpy as jnp
 from types import SimpleNamespace
-from repro.core.compat import make_mesh
+from repro.core import make_mesh
 from repro.models import attention as attn
 from repro.models.sharding import make_recipe, use_recipe
 
@@ -148,7 +148,7 @@ def test_gqa_attention_sp_ring_recipe_matches_no_recipe(distributed):
         """
 import numpy as np, jax, jax.numpy as jnp
 from types import SimpleNamespace
-from repro.core.compat import make_mesh
+from repro.core import make_mesh
 from repro.models import attention as attn
 from repro.models.sharding import make_recipe, use_recipe
 
@@ -212,7 +212,7 @@ def test_ring_attention_kernel_impl_matches_jnp(distributed):
     out = distributed(
         """
 import numpy as np, jax, jax.numpy as jnp
-from repro.core.compat import make_mesh
+from repro.core import make_mesh
 from repro.kernels.ref import attention_ref
 from repro.models import attention as attn
 
@@ -271,7 +271,7 @@ def test_gqa_attention_prefill_chunk_ring_matches_no_recipe(distributed):
         """
 import numpy as np, jax, jax.numpy as jnp
 from types import SimpleNamespace
-from repro.core.compat import make_mesh
+from repro.core import make_mesh
 from repro.models import attention as attn
 from repro.models.sharding import make_recipe, use_recipe
 

@@ -70,7 +70,7 @@ import jax
 from repro import configs
 from repro.models.sharding import make_recipe
 
-from repro.core.compat import make_mesh
+from repro.core import make_mesh
 mesh = make_mesh((2, 4), ('data', 'model'))
 
 # qwen: 40 heads % 4 == 0 -> tp mode on this mesh
@@ -104,7 +104,7 @@ def test_moe_replicated_fallback_warns(distributed):
         """
 import dataclasses, warnings
 from repro import configs
-from repro.core.compat import make_mesh
+from repro.core import make_mesh
 from repro.models.sharding import make_recipe
 
 mesh = make_mesh((2, 4), ('data', 'model'))
@@ -158,7 +158,7 @@ batch = jax.tree.map(jnp.asarray, make_batch(cfg, cell, 0, DataConfig(seed=4)))
 p_ref, o_ref, m_ref = jax.jit(make_train_step(cfg, None, ocfg))(params, opt, batch)
 
 # 4x2 mesh
-from repro.core.compat import make_mesh
+from repro.core import make_mesh
 mesh = make_mesh((4, 2), ('data', 'model'))
 recipe = make_recipe(cfg, mesh)
 specs = lm.build_specs(cfg)

@@ -211,9 +211,9 @@ import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro import configs
 from repro.configs.base import ShapeCell
-from repro.core.compat import make_mesh
+from repro.core import make_mesh
 from repro.core.collectives import shard_all_gatherv_start, shard_reduce_scatterv_start
-from repro.core.compat import shard_map
+from jax import shard_map
 from repro.data.pipeline import DataConfig, make_batch
 from repro.models import lm
 from repro.train.buckets import pack_bucket, unpack_bucket
@@ -260,7 +260,7 @@ rep_tree = jax.tree.map(lambda _: P(), params)
 expl_grads = jax.jit(shard_map(
     grads_body, mesh=mesh,
     in_specs=(rep_tree, jax.tree.map(lambda _: P('data'), batch)),
-    out_specs=rep_tree, check_rep=False))(params, batch)
+    out_specs=rep_tree, check_vma=False))(params, batch)
 for a, b in zip(jax.tree.leaves(base_grads), jax.tree.leaves(expl_grads)):
     assert np.array_equal(np.asarray(a), np.asarray(b)), 'grads not bitwise'
 

@@ -111,6 +111,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Callable
 
+import jax
+
 from .request import Pending
 
 __all__ = ["CommPlan", "ring", "halo", "pipeline", "stagger", "dispatch",
@@ -132,6 +134,14 @@ def intent_of(kind: str) -> str:
     if kind not in _INTENTS:
         raise ValueError(f"unknown plan kind {kind!r} (have {sorted(_INTENTS)})")
     return _INTENTS[kind]
+
+
+def _behind(value, prior):
+    """``value`` unchanged, but ordered after ``prior`` exists: a barrier
+    over both, so no data moves (``prior=None``: ``value`` as is)."""
+    if prior is None:
+        return value
+    return jax.lax.optimization_barrier((value, prior))[0]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -194,7 +204,7 @@ class CommPlan:
             return carry
         return self.epilogue(carry, state)
 
-    def run(self, state, carry, *, double_buffer: bool = True):
+    def run(self, state, carry, *, double_buffer: bool = True, after=None):
         """Emit the program: rotate ``state`` through ``steps`` transfers
         while folding ``compute`` over ``carry``.
 
@@ -202,27 +212,42 @@ class CommPlan:
         ``k``'s compute and waits after it (the overlap window);
         ``double_buffer=False`` starts and waits back-to-back at the
         completion point — same issue path, bit-identical results.
+
+        ``after`` (stagger only) is the completed result of an earlier
+        collective on the same axis; step 0's collective starts behind it.
         """
+        if after is not None and self.kind != "stagger":
+            raise ValueError(f"after= orders stagger plans only, not {self.kind!r}")
         if self.kind == "stagger":
             # round-robin over independent steps (microbatches): every step
             # computes its own partial and issues its own collective; no step
             # consumes another's result, so each transfer's completion hides
             # behind the *other* steps' compute — the continuous-batching
             # decode schedule (microbatch i's reduction behind microbatch
-            # i+1's math).  The blocking form completes each transfer before
-            # the next issue; the waits are pure completion points
-            # (optimization barriers), so both forms are bit-identical.
+            # i+1's math).  Collectives start in order, as nonblocking
+            # collectives on one communicator do: step s's operand is tied
+            # behind the previous collective's completion (a barrier; no
+            # data moves, values are unchanged).  Without the tie the
+            # collectives are independent, and the TPU compiler's all-reduce
+            # combiner fuses them into one tuple collective that waits for
+            # the last partial: the serialized schedule.  Step s's compute
+            # stays untied, so it hides step s-1's collective.  The
+            # blocking form completes each transfer before the next issue;
+            # the waits are pure completion points (optimization
+            # barriers), so both forms are bit-identical.
             if double_buffer:
-                pends = [
-                    self._issue(self.compute(carry, state, s), s)
-                    for s in range(self.steps)
-                ]
+                pends = []
+                for s in range(self.steps):
+                    prior = pends[-1].result if pends else after
+                    part = _behind(self.compute(carry, state, s), prior)
+                    pends.append(self._issue(part, s))
                 done = [p.wait() for p in pends]
             else:
-                done = [
-                    self._issue(self.compute(carry, state, s), s).wait()
-                    for s in range(self.steps)
-                ]
+                done = []
+                for s in range(self.steps):
+                    prior = done[-1] if done else after
+                    part = _behind(self.compute(carry, state, s), prior)
+                    done.append(self._issue(part, s).wait())
             return self._finish(done, state)
         if self.kind == "bucket":
             # ZeRO gradient schedule (see module docstring): issue EVERY

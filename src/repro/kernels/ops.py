@@ -146,18 +146,17 @@ def flash_attention_carry(q, k, v, carry=None, *, q_offset=0, k_offset=0,
     return step(q, k, v, carry, q_offset, k_offset)
 
 
-def flash_decode(q, k_cache, v_cache, cache_len, *, q_positions=None,
+def flash_decode(q, k_cache, v_cache, cache_len, *, q_start=None,
                  impl: str | None = None, **kw):
-    """Flash-decoding attention over the cache.  ``q_positions`` (B, S) are
-    the queries' absolute positions; every caller builds them as a row's
-    start plus ``arange(S)``, and the kernel takes only the start."""
+    """Flash-decoding attention over the cache.  ``q_start`` (B,) is the
+    position of each row's first query; query ``j`` sits at
+    ``q_start + j``."""
     impl = _resolve(impl)
     if impl == "ref":
         return _ref.decode_attention_ref(
-            q, k_cache, v_cache, cache_len, q_positions=q_positions,
+            q, k_cache, v_cache, cache_len, q_start=q_start,
             scale=kw.get("scale"),
         )
-    q_start = None if q_positions is None else q_positions[:, 0]
     return flash_decode_pallas(
         q, k_cache, v_cache, cache_len, q_start=q_start,
         interpret=(impl == "interpret"), **kw,

@@ -175,11 +175,12 @@ def flash_carry_ref(q, k, v, carry=None, *, q_offset=0, k_offset=0,
     return acc_new, m_new, l_new
 
 
-def decode_attention_ref(q, k_cache, v_cache, cache_len, *, q_positions=None,
+def decode_attention_ref(q, k_cache, v_cache, cache_len, *, q_start=None,
                          scale: float | None = None):
     """Reference for :func:`repro.kernels.flash_decode.flash_decode_pallas`:
     dense decode attention over the cache with ring-buffer-aware length
-    masking and the per-row chunk-causality mask.  (The model-facing jnp
+    masking and the per-row chunk-causality mask (query ``j`` of row ``b``
+    sits at ``q_start[b] + j``).  (The model-facing jnp
     path in ``models.attention.attention_decode`` additionally rounds the
     normalized probabilities to the cache dtype under a pinned barrier; this
     oracle keeps everything f32.)"""
@@ -192,10 +193,11 @@ def decode_attention_ref(q, k_cache, v_cache, cache_len, *, q_positions=None,
                    k_cache.astype(jnp.float32)) * scale
     valid = jnp.minimum(cache_len.reshape(B, 1, 1, 1, 1), T)
     mask = jnp.arange(T)[None, None, None, None, :] < valid
-    if q_positions is not None:
+    if q_start is not None:
+        q_pos = q_start.reshape(B, 1) + jnp.arange(S)[None, :]
         mask = mask & (
             jnp.arange(T)[None, None, None, None, :]
-            <= q_positions.reshape(B, 1, 1, S, 1)
+            <= q_pos.reshape(B, 1, 1, S, 1)
         )
     s = jnp.where(mask, s, -1e30)
     p = jax.nn.softmax(s, axis=-1)

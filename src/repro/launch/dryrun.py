@@ -5,9 +5,12 @@ os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=512 "
 # NOTE: the lines above MUST run before any other import (jax locks the
 # device count at first init), which is why the docstring follows them and no
 # `from __future__` import is used in this module.  The gates classify the
-# program's own comm schedule from the CPU compile; the CPU backend's
-# all-reduce combiner would merge independent reductions (e.g. of different
-# decode microbatches) into one tuple all-reduce and so tie their chains.
+# program's own comm schedule from the CPU compile.  The CPU backend drops
+# optimization barriers before its all-reduce combiner runs, so the combiner
+# would merge reductions that a stagger plan orders one behind the other
+# into tuple all-reduces; the TPU compiler keeps them apart
+# (tests/test_tpu_compile.py::test_tp_decode_reductions_stay_apart_for_v5e),
+# and with the CPU pass off the gate sees the same collectives.
 
 DOC = """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
 production mesh and record memory/cost/collective evidence.
@@ -424,7 +427,7 @@ def serve_dryrun(*, arch: str = "phi4-mini-3.8b", slots: int = 8,
     tokens_in = cfg.input_kind != "embeds"
     batch = {"tokens": jax.ShapeDtypeStruct((slots, 1), np.int32)} if tokens_in \
         else {"embeds": jax.ShapeDtypeStruct((slots, 1, cfg.d_model), np.float32)}
-    active = jax.ShapeDtypeStruct((slots,), np.bool_)
+    counts = jax.ShapeDtypeStruct((slots,), np.int32)
 
     out: dict = {"arch": arch, "slots": slots, "max_len": max_len,
                  "grid": list(grid), "microbatches": microbatches,
@@ -432,7 +435,7 @@ def serve_dryrun(*, arch: str = "phi4-mini-3.8b", slots: int = 8,
     for variant, mb in (("staggered", microbatches), ("single", 1)):
         step = make_tp_decode_step(cfg, mesh, slots=slots, microbatches=mb,
                                    attn_impl=attn_impl)
-        compiled = jax.jit(step).lower(params, state, batch, active).compile()
+        compiled = jax.jit(step).lower(params, state, batch, counts).compile()
         st = hlo_walk.analyze(compiled.as_text())
         out[variant] = {
             "collectives": len(st.collectives),

@@ -319,15 +319,16 @@ def _ring_applicable(recipe, q, k) -> bool:
     return R > 1 and S >= 1 and k.shape[2] == S and q.shape[1] % k.shape[1] == 0
 
 
-def attention_decode(q, k_cache, v_cache, cache_len, *, q_positions=None,
+def attention_decode(q, k_cache, v_cache, cache_len, *, q_start=None,
                      impl: str | None = None, block: int = 512):
     """q (B,H,S,D) new queries; caches (B,G,T,D); positions >= cache_len are
-    masked.  ``q_positions`` (B,S) are the queries' absolute positions: cache
-    slot ``t`` is visible to query ``j`` iff ``t <= q_positions[b, j]`` —
-    the causal mask *within* a multi-token chunk (whole-prompt prefill) and
-    the per-slot mask under continuous batching, where each batch row sits
-    at its own position.  With S == 1 and uniform positions this reduces to
-    the classic single-token decode mask.
+    masked.  ``q_start`` (B,) is each row's first query position: query
+    ``j`` of row ``b`` sits at ``q_start[b] + j`` and sees cache slot ``t``
+    iff ``t <= q_start[b] + j`` — the causal mask *within* a multi-token
+    chunk (whole-prompt prefill) and the per-slot mask under continuous
+    batching, where each batch row sits at its own position.  With S == 1
+    and uniform starts this reduces to the classic single-token decode
+    mask.
 
     Reading the whole cache is the roofline minimum for decode; softmax
     reductions over a sharded cache-seq dim become the distributed
@@ -345,7 +346,7 @@ def attention_decode(q, k_cache, v_cache, cache_len, *, q_positions=None,
     impl = impl or ("pallas" if jax.default_backend() == "tpu" else "jnp")
     if impl not in ("jnp", "ref"):
         o = ops.flash_decode(q, k_cache, v_cache, cache_len,
-                             q_positions=q_positions, impl=impl, bk=block)
+                             q_start=q_start, impl=impl, bk=block)
         # same pinned boundary as the jnp path's rounded probabilities: the
         # kernel output rounds to the activation dtype behind a barrier so
         # schedule variants cannot fold the convert differently
@@ -360,10 +361,11 @@ def attention_decode(q, k_cache, v_cache, cache_len, *, q_positions=None,
     # every slot is valid
     valid = jnp.minimum(cache_len.reshape(B, 1, 1, 1, 1), T)
     mask = jnp.arange(T)[None, None, None, None, :] < valid
-    if q_positions is not None:
+    if q_start is not None:
+        q_pos = q_start.reshape(B, 1) + jnp.arange(S)[None, :]
         mask = mask & (
             jnp.arange(T)[None, None, None, None, :]
-            <= q_positions.reshape(B, 1, 1, S, 1)
+            <= q_pos.reshape(B, 1, 1, S, 1)
         )
     s = jnp.where(mask, s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
@@ -443,8 +445,9 @@ def gqa_attention(p, x, *, n_heads: int, n_kv: int, head_dim: int, rope_theta: f
             # rows are bitwise unchanged) and slice last: the ragged pad
             # slice is terminal instead of a mid-graph reshard.
             return shard_act(out, "hidden")[:, :S], new_cache
-        q_pos = positions if getattr(positions, "ndim", 1) == 2 else None
-        o = pin(attention_decode(q, kc, vc, new_len, q_positions=q_pos,
+        # a decode chunk's positions are each row's start + arange(S)
+        q_start = positions[:, 0] if getattr(positions, "ndim", 1) == 2 else None
+        o = pin(attention_decode(q, kc, vc, new_len, q_start=q_start,
                                  impl=attn_impl, block=block))
         out = pin(jnp.einsum("bhsd,hdm->bsm", o, p["wo"].astype(x.dtype)))
         return shard_act(out, "hidden"), new_cache

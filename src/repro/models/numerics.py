@@ -14,9 +14,9 @@ distributed-math error anywhere.
 :func:`pin` places an ``optimization_barrier`` at a dtype boundary so the
 round really happens there, making the emitted values a function of the op
 sequence alone, not of the compilation schedule.  It is active only inside
-:func:`pinned_rounding` — the serving engine enters it for decode steps
-(both the oracle and TP paths), while training/prefill keep the unpinned
-fast path.  This is what makes the distributed engine's greedy stream
+:func:`pinned_rounding` — the serving engine enters it for its prefill and
+decode steps (both the oracle and TP paths), while training keeps the
+unpinned fast path.  This is what makes the distributed engine's greedy stream
 token-for-token the single-host oracle's.
 """
 from __future__ import annotations

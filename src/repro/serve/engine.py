@@ -12,12 +12,13 @@ comm layer" notes):
     through ``lm.decode_step(prefill=True)``; under an ``sp_ring`` recipe
     the chunk's attention is the sequence-parallel ring plan — the
     ``Allgatherv``-over-seq-shards phase;
-  * **decode** runs either the GSPMD path (single host / recipe) or — given
-    a ``(data, model)`` mesh and ``microbatches`` — the explicit
-    tensor-parallel step of :mod:`repro.serve.tp_decode`, whose per-layer
-    reductions are issued as non-blocking ``Pending`` collectives staggered
-    behind the next microbatch's compute (``Iallreduce``/``Iallgather`` per
-    layer, nothing on the critical path — what ``--serve`` dry-runs gate).
+  * **decode** runs the GSPMD path (single host / recipe);
+  * given a ``(data, model)`` mesh and ``microbatches``, both run instead
+    through the explicit tensor-parallel step of
+    :mod:`repro.serve.tp_decode`, whose per-layer reductions are issued as
+    non-blocking ``Pending`` collectives staggered behind the next
+    microbatch's compute (``Iallreduce``/``Iallgather`` per layer, nothing
+    on the critical path — what ``--serve`` dry-runs gate).
 
 The single-host engine (no mesh) is the bitwise oracle the distributed
 configuration is tested against: same per-row cache semantics, same greedy
@@ -26,7 +27,6 @@ sampling, token-for-token.
 from __future__ import annotations
 
 import dataclasses
-from contextlib import nullcontext
 from typing import Any, Callable
 
 import jax
@@ -117,10 +117,11 @@ def _reset_slot_rows(caches, i: int):
 class Engine:
     """Slot-based continuous batching over the shared decode path.
 
-    ``recipe`` shards the GSPMD path (prefill always; decode too unless an
-    explicit TP step is requested).  ``mesh`` + ``microbatches`` switch
-    decode to the explicit tensor-parallel step with staggered non-blocking
-    collectives (:func:`repro.serve.tp_decode.make_tp_decode_step`).
+    ``recipe`` shards the GSPMD path.  ``mesh`` + ``microbatches`` switch
+    prefill and decode to the explicit tensor-parallel step with staggered
+    non-blocking collectives
+    (:func:`repro.serve.tp_decode.make_tp_decode_step`), with the weights
+    and the KV cache committed to its layout.
     """
 
     def __init__(self, cfg, params, scfg: ServeConfig, recipe=None, *,
@@ -146,34 +147,31 @@ class Engine:
         self._chunk_prefill = cfg.family in _CHUNK_FAMILIES
 
         def gspmd_step(params, state, batch, counts, *, prefill=False):
-            # Decode runs under pinned rounding: activation-dtype boundaries
+            # Steps run under pinned rounding: activation-dtype boundaries
             # materialize where the source says, so the scan-fused oracle jit
             # and the unrolled TP shard_map emit the same number stream (see
-            # models/numerics.py).  Prefill stays unpinned — both engine
-            # modes share this exact prefill program, bitwise by identity.
-            ctx = nullcontext() if prefill else pinned_rounding()
-            with use_recipe(self.recipe), ctx:
+            # models/numerics.py).
+            with use_recipe(self.recipe), pinned_rounding():
                 return lm.decode_step(params, state, batch, cfg,
                                       new_counts=counts, prefill=prefill)
 
         # the compiled step programs: (params, state, batch, counts) ->
         # (logits, state); the state is donated, so the KV cache is updated
         # in place instead of copied every step
-        self.prefill_fn = jax.jit(
-            lambda p, s, b, c: gspmd_step(p, s, b, c, prefill=True), donate_argnums=1)
         if mesh is not None and microbatches:
-            from repro.serve.tp_decode import make_tp_decode_step
+            from repro.serve.tp_decode import make_tp_decode_step, place_tp
 
             tp = make_tp_decode_step(cfg, mesh, slots=B, microbatches=microbatches,
                                      attn_impl=cfg.attn_impl)
-            # NOTE: params/state are deliberately NOT committed to the TP
-            # layout here — the GSPMD prefill jit would then compile
-            # distributed math whose FP reduction order diverges from the
-            # single-host oracle's; uncommitted inputs keep prefill bitwise
-            # the oracle and let the shard_map reshard at the decode boundary
-            self.decode_fn = jax.jit(lambda p, s, b, c: tp(p, s, b, c > 0),
-                                     donate_argnums=1)
+            # weights and cache live in the TP layout from here on, each
+            # device holding its shard; the one TP program runs both the
+            # prefill chunks and the decode steps, so neither reshards them
+            self.params, self.state = place_tp(cfg, mesh, params, self.state)
+            self.prefill_fn = self.decode_fn = jax.jit(tp, donate_argnums=1)
         else:
+            self.prefill_fn = jax.jit(
+                lambda p, s, b, c: gspmd_step(p, s, b, c, prefill=True),
+                donate_argnums=1)
             self.decode_fn = jax.jit(gspmd_step, donate_argnums=1)
 
     # ------------------------------------------------------------ public ----

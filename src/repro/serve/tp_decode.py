@@ -21,6 +21,10 @@ proves serializes nothing.  With ``microbatches=1`` the same program has no
 sibling compute and every reduction lands on the critical path — the
 negative control.
 
+The same step runs an admission-time prefill chunk (S > 1 tokens per row),
+so a serving engine keeps its weights and KV cache in this layout for both
+step kinds (:func:`place_tp`) and no device holds the whole model.
+
 Scope: the attention families with plain GQA blocks (``dense``/``audio``),
 with or without QKV biases (bias shards ride the head/KV-group shards and
 are added between each projection and rope, the oracle's pinned order);
@@ -40,7 +44,7 @@ from repro.models.attention import KVCache, apply_rope, attention_decode, rope_a
 from repro.models.blocks import rmsnorm
 from repro.models.numerics import pin as _pin, pinned_rounding
 
-__all__ = ["make_tp_decode_step", "tp_decode_specs", "DECODE_TP_PLAN_INTENT"]
+__all__ = ["make_tp_decode_step", "tp_decode_specs", "place_tp", "DECODE_TP_PLAN_INTENT"]
 
 # declared overlap intent of the decode schedule, consumed by the --serve
 # dry run's plan/HLO agreement gate
@@ -105,15 +109,40 @@ def tp_decode_specs(cfg, *, stacked: bool = True):
     return params, kv, P(*lead, "data")
 
 
+def place_tp(cfg, mesh, params, state):
+    """Commit ``params`` and a stacked :class:`repro.models.lm.DecodeState`
+    to the TP layout of :func:`tp_decode_specs`: every device then holds
+    only its shard, and a step reads the weights and the cache where they
+    live instead of resharding them."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    p_specs, kv_spec, len_spec = tp_decode_specs(cfg)
+
+    def put(spec, x):
+        return jax.device_put(x, NamedSharding(mesh, spec))
+
+    params = jax.tree.map(put, p_specs, params, is_leaf=lambda s: isinstance(s, P))
+    caches = state.caches
+    state = lm.DecodeState(
+        caches=KVCache(put(kv_spec, caches.k), put(kv_spec, caches.v),
+                       put(len_spec, caches.length)),
+        positions=put(P("data"), state.positions),
+    )
+    return params, state
+
+
 def make_tp_decode_step(cfg, mesh, *, slots: int, microbatches: int = 2,
                         double_buffer: bool = True, attn_impl: str | None = None):
-    """Build ``step(params, state, batch, active) -> (logits, new_state)``.
+    """Build ``step(params, state, batch, counts) -> (logits, new_state)``.
 
     ``state`` is the stacked :class:`repro.models.lm.DecodeState`;
-    ``batch`` holds ``tokens`` (B, S) or ``embeds`` (B, S, m); ``active``
-    (B,) bool marks slots carrying a real token this step — inactive rows'
-    cache writes are masked out and their positions do not advance (the
-    same per-row semantics as the fixed single-host ``decode_step``).
+    ``batch`` holds ``tokens`` (B, S) or ``embeds`` (B, S, m); ``counts``
+    (B,) int32 says how many of the S tokens are valid per slot, 0 for an
+    idle slot — idle rows' cache writes are masked out and positions
+    advance by the count (the per-row semantics of the single-host
+    ``lm.decode_step``).  S == 1 is a decode step; S > 1 a whole-prompt
+    prefill chunk.  ``logits`` (B, 1, vocab) are those of each row's last
+    valid token, like a prefill ``lm.decode_step``'s.
 
     ``attn_impl`` picks the per-layer attention kernel under the stagger
     plan (see ``models/attention.py``'s dispatch table): ``"pallas"`` /
@@ -138,10 +167,9 @@ def make_tp_decode_step(cfg, mesh, *, slots: int, microbatches: int = 2,
 
     p_specs, kv_spec, len_spec = tp_decode_specs(cfg)
     in_batch = P("data", None) if tokens_in else P("data", None, None)
-
-    def body(params, k_all, v_all, length_all, positions, inputs, active):
+    def body(params, k_all, v_all, length_all, positions, inputs, counts):
         midx = jax.lax.axis_index("model")
-        counts = active.astype(jnp.int32)
+        active = counts > 0
         Bl = positions.shape[0]
         bm = Bl // mb
         S = inputs.shape[1]
@@ -167,6 +195,7 @@ def make_tp_decode_step(cfg, mesh, *, slots: int, microbatches: int = 2,
         a_mb = [active[r] for r in rows]
         c_mb = [counts[r] for r in rows]
         p_mb = [pos2d[r] for r in rows]
+        st_mb = [positions[r] for r in rows]
 
         def masked_update(cache, new, length, act_rows):
             size = cache.shape[2]
@@ -178,6 +207,10 @@ def make_tp_decode_step(cfg, mesh, *, slots: int, microbatches: int = 2,
             return jnp.where(act_rows[:, None, None, None], upd, cache)
 
         new_k_layers, new_v_layers = [], []
+        # every stage's all-reduces start behind the previous stage's last
+        # one (one communicator's issue order), so no compiler combines a
+        # reduction with an independent one of another stage
+        last = None
         blocks = params["blocks"]
         for l in range(L):
             ln1 = blocks["ln1"][l]
@@ -218,14 +251,17 @@ def make_tp_decode_step(cfg, mesh, *, slots: int, microbatches: int = 2,
                 new_k_l[s] = nk
                 new_v_l[s] = nv
                 o = _pin(attention_decode(q, nk, nv, length + c_mb[s],
-                                          q_positions=p_mb[s], impl=attn_impl))
+                                          q_start=st_mb[s], impl=attn_impl))
                 # local head shard's partial projection — the transfer stage
                 # issues its Iallreduce; the next microbatch's math hides it.
                 # Partials stay f32 through the reduction and are rounded to
                 # the activation dtype once, post-psum: splitting the dot
                 # across ranks then only perturbs f32-level accumulation
                 # order, so the reduced sum rounds to the same low-precision
-                # value as the oracle's single full-contraction dot.
+                # value as the oracle's single full-contraction dot.  (The
+                # TPU compiler folds that convert into a bf16-typed
+                # all-reduce that still sums in f32: on a v5e its result
+                # equals the exact sum rounded once, element for element.)
                 return jnp.einsum("bhsd,hdm->bsm", o, wo.astype(xi.dtype),
                                   preferred_element_type=jnp.float32)
 
@@ -234,7 +270,7 @@ def make_tp_decode_step(cfg, mesh, *, slots: int, microbatches: int = 2,
                 transfer=lambda part, s: shard_all_reduce_start(part, "model"),
                 compute=attn_compute,
                 epilogue=lambda done, _s: [_pin(d.astype(act_dt)) for d in done],
-            ).run(None, None, double_buffer=double_buffer)
+            ).run(None, None, double_buffer=double_buffer, after=last)
             xs = [_pin(xs[s] + attn_done[s]) for s in range(mb)]
 
             ffn = blocks["ffn"]
@@ -277,13 +313,16 @@ def make_tp_decode_step(cfg, mesh, *, slots: int, microbatches: int = 2,
                 transfer=lambda part, s: shard_all_reduce_start(part, "model"),
                 compute=ffn_compute,
                 epilogue=ffn_epilogue,
-            ).run(None, None, double_buffer=double_buffer)
+            ).run(None, None, double_buffer=double_buffer, after=attn_done[-1])
             xs = [_pin(xs[s] + ffn_done[s]) for s in range(mb)]
+            last = ffn_done[-1]
 
             new_k_layers.append(jnp.concatenate(new_k_l, axis=0))
             new_v_layers.append(jnp.concatenate(new_v_l, axis=0))
 
         x = jnp.concatenate(xs, axis=0)
+        # only each row's last valid token predicts the next one
+        x = x[jnp.arange(Bl), jnp.maximum(counts - 1, 0)][:, None]
         xn = _pin(rmsnorm(params["final_norm"], x))
         head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
         # vocab-sharded head: the contraction dim is replicated, so each
@@ -305,13 +344,13 @@ def make_tp_decode_step(cfg, mesh, *, slots: int, microbatches: int = 2,
         check_vma=False,
     )
 
-    def step(params, state, batch, active):
+    def step(params, state, batch, counts):
         caches = state.caches
         inputs = batch["tokens"] if tokens_in else batch["embeds"]
         with pinned_rounding():
             logits, nk, nv, nlen, npos = fn(
                 params, caches.k, caches.v, caches.length, state.positions,
-                inputs, active,
+                inputs, counts.astype(jnp.int32),
             )
         new_state = lm.DecodeState(caches=KVCache(nk, nv, nlen), positions=npos)
         return logits, new_state

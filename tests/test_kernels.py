@@ -314,17 +314,17 @@ def test_flash_decode_gqa(hq, hkv):
 
 
 def test_flash_decode_chunk_positions():
-    """Multi-token chunks with per-row absolute positions: cache slot t is
-    visible to query j iff t <= q_positions[b, j] — continuous batching's
+    """Multi-token chunks with per-row start positions: cache slot t is
+    visible to query j iff t <= q_start[b] + j — continuous batching's
     per-slot causal mask."""
     B, H, G, S, T, D = 2, 4, 2, 4, 64, 16
     q = jnp.asarray(RNG.standard_normal((B, H, S, D)), jnp.float32)
     kc = jnp.asarray(RNG.standard_normal((B, G, T, D)), jnp.float32)
     vc = jnp.asarray(RNG.standard_normal((B, G, T, D)), jnp.float32)
-    pos = jnp.asarray([[10, 11, 12, 13], [0, 1, 2, 3]], jnp.int32)
+    start = jnp.asarray([10, 0], jnp.int32)
     clen = jnp.asarray([14, 4], jnp.int32)
-    out = ops.flash_decode(q, kc, vc, clen, q_positions=pos, impl="interpret", bk=32)
-    expect = ref.decode_attention_ref(q, kc, vc, clen, q_positions=pos)
+    out = ops.flash_decode(q, kc, vc, clen, q_start=start, impl="interpret", bk=32)
+    expect = ref.decode_attention_ref(q, kc, vc, clen, q_start=start)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                rtol=2e-4, atol=2e-4)
 
@@ -338,11 +338,10 @@ def test_flash_decode_chunk_row_blocks():
     kc = jnp.asarray(RNG.standard_normal((B, G, T, D)), jnp.float32)
     vc = jnp.asarray(RNG.standard_normal((B, G, T, D)), jnp.float32)
     start = np.array([0, 37], np.int32)
-    pos = jnp.asarray(start[:, None] + np.arange(S)[None, :], jnp.int32)
     clen = jnp.asarray(start + S, jnp.int32)
-    out = ops.flash_decode(q, kc, vc, clen, q_positions=pos, impl="interpret",
-                           bq=16, bk=32)
-    expect = ref.decode_attention_ref(q, kc, vc, clen, q_positions=pos)
+    out = ops.flash_decode(q, kc, vc, clen, q_start=jnp.asarray(start),
+                           impl="interpret", bq=16, bk=32)
+    expect = ref.decode_attention_ref(q, kc, vc, clen, q_start=jnp.asarray(start))
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                rtol=2e-4, atol=2e-4)
 

@@ -244,6 +244,16 @@ def test_stagger_executor_round_robin_issue_wait_placement():
                         ("comp", 1), ("xfer", 1), ("wait", 1),
                         ("comp", 2), ("xfer", 2), ("wait", 2)]
 
+    # ``after`` orders step 0's collective behind an earlier one: values and
+    # issue/wait order are unchanged; other plan kinds refuse it
+    from repro.core.plan import ring
+
+    trace.clear()
+    assert [int(d) for d in plan.run(None, None, after=np.float32(7))] == [10, 20, 30]
+    assert trace == order_db
+    with pytest.raises(ValueError, match="stagger plans only"):
+        ring(2, transfer=transfer, compute=compute).run(None, None, after=np.float32(7))
+
 
 def test_bucket_plan_intent_and_validation():
     from repro.core.plan import CommPlan, bucket, intent_of

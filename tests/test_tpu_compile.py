@@ -7,14 +7,18 @@ The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and every test worker
 imports this file.
 """
+import dataclasses
 import os
+import re
 
+import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 
+from repro import configs
 from repro.kernels.flash_attention import flash_attention_carry_pallas, flash_attention_pallas
 from repro.kernels.flash_decode import flash_decode_pallas
 from repro.kernels.gemm import gemm_panel_pallas
@@ -126,4 +130,44 @@ def test_gemm_panel_compiles_for_v5e(one_chip, no_cache):
         _arg(one_chip, (1024, 2560), f32),
         _arg(one_chip, (), jnp.int32),
     )
+    assert "tpu_custom_call" in txt
+
+
+@pytest.mark.parametrize("S", [1, 1024], ids=["decode", "prefill_chunk"])
+def test_tp_decode_reductions_stay_apart_for_v5e(topo, no_cache, S):
+    """The explicit TP step at phi4-mini widths (depth cut to 2 layers) on a
+    (1, 4) mesh: each microbatch's reduction stays its own all-reduce, one
+    ordered behind the other, so each can hide behind the next
+    microbatch's compute.  Without that order the TPU compiler combines
+    the two microbatches' reductions into one tuple all-reduce, which
+    waits for both partials."""
+    from repro.models import lm
+    from repro.serve.tp_decode import make_tp_decode_step, tp_decode_specs
+
+    L, slots, mb = 2, 8, 2
+    cfg = dataclasses.replace(configs.get("phi4-mini-3.8b"),
+                              param_dtype=jnp.bfloat16, n_layers=L)
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"))
+    p_specs, kv_spec, len_spec = tp_decode_specs(cfg)
+    P = jax.sharding.PartitionSpec
+
+    def place(spec, tree):
+        return jax.tree.map(lambda x: _arg(NamedSharding(mesh, spec), x.shape, x.dtype), tree)
+
+    params = jax.tree.map(place, p_specs,
+                          jax.eval_shape(lambda: lm.init_model(cfg, jax.random.PRNGKey(0))),
+                          is_leaf=lambda x: isinstance(x, P))
+    cache = jax.eval_shape(lambda: lm.init_cache(cfg, slots, 2048))
+    rows = NamedSharding(mesh, P("data"))
+    state = lm.DecodeState(
+        caches=type(cache)(place(kv_spec, cache.k), place(kv_spec, cache.v),
+                           place(len_spec, cache.length)),
+        positions=_arg(rows, (slots,), jnp.int32))
+    batch = {"tokens": _arg(NamedSharding(mesh, P("data", None)), (slots, S), jnp.int32)}
+    step = make_tp_decode_step(cfg, mesh, slots=slots, microbatches=mb, attn_impl="pallas")
+    txt = _compiled_text(step, params, state, batch, _arg(rows, (slots,), jnp.int32))
+    reductions = re.findall(r"= (.*?) all-reduce(?:-start)?\(", txt)
+    # the embedding's routing psum, then one per (layer, stage, microbatch)
+    assert len(reductions) == 1 + L * 2 * mb
+    assert not [r for r in reductions if r.startswith("(")], "tuple all-reduce"
     assert "tpu_custom_call" in txt

@@ -1,18 +1,13 @@
-"""Benchmark harness entry point: one section per paper table/figure plus
-the LM-framework extensions.  Prints ``name,us_per_call,derived`` CSV blocks.
+"""Entry point for the paper's tables that are not timings.  Prints
+``name,us_per_call,derived`` CSV blocks.
 
   * feature_matrix  — paper Table 1 (programmatic feature checks)
-  * relayout_bench  — paper §3.2 transform taxonomy microbench
-  * gemm_layouts    — paper Fig. 3 (8 C/A/B layout configs, MINI+EXTRALARGE,
-                      8 ranks) — pass --quick to use MINI only
-  * lm_step_bench   — per-arch smoke train/decode step times
-  * roofline_table  — §Roofline aggregation of the dry-run artifacts
 
-Usage: PYTHONPATH=src python -m benchmarks.run [--quick] [--skip gemm_layouts]
+Usage: PYTHONPATH=src python -m benchmarks.run [--skip feature_matrix]
 
 Each section runs in a child process of its own and this parent never
-imports JAX: a device belongs to one process at a time, and a parent holding
-it would leave a section's child (``gemm_layouts`` starts one) without it.
+imports JAX: a device belongs to one process at a time.  Performance is
+measured on the chip by ``bench/run.py`` (``BENCHMARK.json``).
 """
 import argparse
 import os
@@ -25,22 +20,12 @@ ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 
 def main() -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--quick", action="store_true", help="smaller datasets")
     ap.add_argument("--skip", action="append", default=[])
     args = ap.parse_args()
 
-    datasets = ("MINI",) if args.quick else ("MINI", "EXTRALARGE")
     # (skip key, title, module, call that returns the section's lines)
     sections = [
         ("feature_matrix", "feature_matrix (paper Table 1)", "feature_matrix", "run()"),
-        ("relayout_bench", "relayout_bench (paper §3.2)", "relayout_bench", "run()"),
-        ("gemm_layouts", "gemm_layouts (paper Fig. 3)", "gemm_layouts",
-         f"run(datasets={datasets!r})"),
-        ("lm_step_bench", "lm_step_bench (framework)", "lm_step_bench", "run()"),
-        ("roofline_table", "roofline_table singlepod (§Roofline)", "roofline_table",
-         "run('singlepod')"),
-        ("roofline_table", "roofline_table multipod (§Dry-run)", "roofline_table",
-         "run('multipod')"),
     ]
 
     env = dict(os.environ)

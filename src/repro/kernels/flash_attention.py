@@ -216,6 +216,7 @@ def flash_attention_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, Sq_p, Dv), q.dtype),
         interpret=interpret,
+        name="flash_attention_pallas",
     )(offs, q, k, v)
     return out[:, :, :Sq] if Sq_p != Sq else out
 
@@ -311,6 +312,7 @@ def flash_attention_carry_pallas(
         # flat operands: offs, q, k, v, acc, m, l — carry updates in place
         input_output_aliases={4: 0, 5: 1, 6: 2},
         interpret=interpret,
+        name="flash_attention_carry_pallas",
     )(offs, q, k, v, acc, m, l)
     m_o, l_o = m_o[..., 0], l_o[..., 0]
     if Sq_p != Sq:

@@ -166,6 +166,7 @@ def flash_decode_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qg.shape[:4] + (Dv,), q.dtype),
         interpret=interpret,
+        name="flash_decode_pallas",
     )(lens, starts, qg, k_cache, v_cache)
     if S_p != S:
         o = o[:, :, :, :S]

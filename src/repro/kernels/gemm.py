@@ -160,6 +160,7 @@ def gemm_pallas(
         out_shape=jax.ShapeDtypeStruct(out_shape, out_dtype),
         scratch_shapes=[_vmem((bm_, bn_), jnp.float32)],
         interpret=interpret,
+        name="gemm_pallas",
     )(*operands)
 
 
@@ -267,4 +268,5 @@ def gemm_panel_pallas(
         out_shape=jax.ShapeDtypeStruct(panel.shape, panel.dtype),
         input_output_aliases={3: 0},  # flat operands: jb, a, b, panel
         interpret=interpret,
+        name="gemm_panel_pallas",
     )(jb_arr, a, b, panel)

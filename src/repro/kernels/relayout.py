@@ -49,5 +49,6 @@ def transpose_tiled_pallas(x, *, bm: int = 256, bn: int = 256, interpret: bool =
         out_specs=pl.BlockSpec((1, bn_, bm_), lambda b, i, j: (b, j, i)),
         out_shape=jax.ShapeDtypeStruct((B, N, M), x.dtype),
         interpret=interpret,
+        name="transpose_tiled_pallas",
     )(x3)
     return out.reshape(*lead, N, M)

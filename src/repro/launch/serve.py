@@ -13,8 +13,15 @@ collectives staggered behind the next microbatch's compute.
 ``--fake-devices`` forces that many XLA host devices (CPU bring-up).
 ``--max-steps`` bounds the decode loop; requests still resident when the
 budget runs out are reported as in-flight with their partial outputs.
+``--trace-dir DIR`` runs the submit/run loop under ``jax.profiler.trace(DIR)``:
+open DIR in TensorBoard or Perfetto to see the engine's phase spans
+(``engine.admit``, ``engine.prefill_launch``, ``engine.decode_launch``,
+``engine.fetch``, ``engine.sample``) and each request's ``engine.queued``
+span, with their arguments (admitted and queued requests, KV bytes in use,
+rows per step, prefill bucket), on the device events' clock.
 """
 import argparse
+import contextlib
 import os
 import sys
 import time
@@ -38,6 +45,8 @@ def main() -> None:
                     help="stagger depth of the TP decode comm plan")
     ap.add_argument("--fake-devices", type=int, default=0,
                     help="force N XLA host devices (CPU bring-up of --grid)")
+    ap.add_argument("--trace-dir", default=None, metavar="DIR",
+                    help="write a profiler trace of the serving loop to DIR")
     args = ap.parse_args()
 
     if args.fake_devices:
@@ -83,14 +92,16 @@ def main() -> None:
                        temperature=args.temperature, eos_token=-1)
     engine = Engine(cfg, params, scfg, mesh=mesh, microbatches=microbatches)
     rng = np.random.default_rng(0)
-    t0 = time.time()
+    traced = jax.profiler.trace(args.trace_dir) if args.trace_dir else contextlib.nullcontext()
+    t0 = time.perf_counter()
     total_new = 0
-    for rid in range(args.requests):
-        prompt = rng.integers(2, min(cfg.vocab, 1000), size=rng.integers(3, 10)).tolist()
-        engine.submit(rid, prompt, args.max_new)
-        total_new += args.max_new
-    done = engine.run(max_steps=args.max_steps)
-    dt = time.time() - t0
+    with traced:
+        for rid in range(args.requests):
+            prompt = rng.integers(2, min(cfg.vocab, 1000), size=rng.integers(3, 10)).tolist()
+            engine.submit(rid, prompt, args.max_new)
+            total_new += args.max_new
+        done = engine.run(max_steps=args.max_steps)
+    dt = time.perf_counter() - t0
     for rid in sorted(done):
         print(f"[serve] req {rid}: {done[rid]}")
     for rid, toks in sorted(engine.in_flight.items()):

@@ -23,6 +23,26 @@ comm layer" notes):
 The single-host engine (no mesh) is the bitwise oracle the distributed
 configuration is tested against: same per-row cache semantics, same greedy
 sampling, token-for-token.
+
+Tracing: the host loop marks its phases with ``jax.profiler.TraceAnnotation``
+spans, which land in a profiler trace on the device events' clock and cost
+about a microsecond each while no profiler records.  The phase spans tile one
+iteration of :meth:`Engine.run`:
+
+  * ``engine.admit`` (args ``admitted``, ``queued``, ``kv_valid_bytes``):
+    a round that admits at least one request — queue pops, ledger, slot
+    state reset;
+  * ``engine.prefill_launch`` (``rows``, ``bucket``): the prefill batch
+    built, copied to the device and its step program(s) dispatched;
+  * ``engine.decode_launch`` (``rows``): the decode feed built, copied and
+    the decode step dispatched;
+  * ``engine.fetch``: the logits sliced and pulled to the host, which waits
+    here for the decode program;
+  * ``engine.sample`` (``rows``): next tokens chosen, ledger advanced,
+    finished slots released.
+
+Besides, ``engine.queued`` (``rid``) runs per request from :meth:`submit` to
+its admission.
 """
 from __future__ import annotations
 
@@ -32,6 +52,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.models import lm
 from repro.models.numerics import pinned_rounding
@@ -137,7 +158,9 @@ class Engine:
             positions=jnp.zeros((B,), jnp.int32),
         )
         self.slots = [_Slot() for _ in range(B)]
-        self.queue: list[tuple[int, list[int], Any, int]] = []
+        # (request id, prompt, prompt embeds, max new tokens, its open
+        # ``engine.queued`` span)
+        self.queue: list[tuple[int, list[int], Any, int, TraceAnnotation]] = []
         self.finished: dict[int, list[int]] = {}
         self.ledger = KVLedger(slots=B, max_len=scfg.max_len,
                                bytes_per_pos=_kv_bytes_per_pos(cfg))
@@ -195,7 +218,9 @@ class Engine:
                 f"request {request_id}: prompt {plen} + {max_new_tokens} new "
                 f"exceeds max_len {self.scfg.max_len}"
             )
-        self.queue.append((request_id, prompt, prompt_embeds, max_new_tokens))
+        queued = TraceAnnotation("engine.queued", rid=request_id)
+        queued.__enter__()
+        self.queue.append((request_id, prompt, prompt_embeds, max_new_tokens, queued))
 
     @property
     def in_flight(self) -> dict[int, list[int]]:
@@ -217,12 +242,19 @@ class Engine:
 
     # ---------------------------------------------------------- internals ----
     def _fill_slots(self) -> None:
+        free = [i for i, slot in enumerate(self.slots) if slot.request_id is None]
+        n = min(len(free), len(self.queue))
+        if not n:
+            return
         newly: list[tuple[int, list[int], Any]] = []
-        for i, slot in enumerate(self.slots):
-            if slot.request_id is None and self.queue:
-                rid, prompt, embeds, max_new = self.queue.pop(0)
+        with TraceAnnotation("engine.admit", admitted=n, queued=len(self.queue) - n,
+                             kv_valid_bytes=self.ledger.valid_bytes()):
+            for i in free[:n]:
+                rid, prompt, embeds, max_new, queued = self.queue.pop(0)
+                queued.__exit__(None, None, None)
                 plen = len(embeds) if embeds is not None else len(prompt)
                 self.ledger.admit(i, plen, max_new)
+                slot = self.slots[i]
                 slot.request_id = rid
                 slot.tokens = list(prompt)
                 slot.remaining = max_new
@@ -232,8 +264,7 @@ class Engine:
                     positions=self.state.positions.at[i].set(0),
                 )
                 newly.append((i, prompt, embeds))
-        if newly:
-            self._prefill(newly)
+        self._prefill(newly)
 
     # ------------------------------------------------------------ prefill ----
     def _prefill(self, newly) -> None:
@@ -246,85 +277,94 @@ class Engine:
         whole prompt as one ``prefill=True`` chunk (the sp_ring batched
         prefill path); recurrent/moe families step token-by-token under the
         same masking."""
+        with TraceAnnotation("engine.prefill_launch") as span:
+            B = self.scfg.batch_slots
+            feeds = []  # (slot, ids[:-1] or embeds[:-1])
+            for i, prompt, embeds in newly:
+                feed = embeds[:-1] if embeds is not None else prompt[:-1]
+                if len(feed):
+                    feeds.append((i, feed))
+            if not feeds:
+                return
+            S = max(len(f) for _, f in feeds)
+            if self._chunk_prefill:
+                S = min(self.scfg.max_len, 1 << (S - 1).bit_length())  # bucket: fewer recompiles
+                span.set_metadata(rows=len(feeds), bucket=S)
+                counts = np.zeros((B,), np.int32)
+                if self._embeds_in:
+                    buf = np.zeros((B, S, self.cfg.d_model), np.float32)
+                else:
+                    buf = np.zeros((B, S), np.int32)
+                for i, feed in feeds:
+                    buf[i, : len(feed)] = feed
+                    counts[i] = len(feed)
+                batch = ({"embeds": jnp.asarray(buf)} if self._embeds_in
+                         else {"tokens": jnp.asarray(buf)})
+                _, self.state = self.prefill_fn(self.params, self.state, batch,
+                                                jnp.asarray(counts))
+                for i, feed in feeds:
+                    self.ledger.advance(i, len(feed))
+                return
+            span.set_metadata(rows=len(feeds), bucket=1)
+            for t in range(S):
+                counts = np.zeros((B,), np.int32)
+                if self._embeds_in:
+                    buf = np.zeros((B, 1, self.cfg.d_model), np.float32)
+                else:
+                    buf = np.zeros((B, 1), np.int32)
+                for i, feed in feeds:
+                    if t < len(feed):
+                        buf[i, 0] = feed[t]
+                        counts[i] = 1
+                        self.ledger.advance(i, 1)
+                batch = ({"embeds": jnp.asarray(buf)} if self._embeds_in
+                         else {"tokens": jnp.asarray(buf)})
+                _, self.state = self.prefill_fn(self.params, self.state, batch,
+                                                jnp.asarray(counts))
+
+    # ------------------------------------------------------------- decode ----
+    def _decode_once(self) -> None:
         B = self.scfg.batch_slots
-        feeds = []  # (slot, ids[:-1] or embeds[:-1])
-        for i, prompt, embeds in newly:
-            feed = embeds[:-1] if embeds is not None else prompt[:-1]
-            if len(feed):
-                feeds.append((i, feed))
-        if not feeds:
-            return
-        S = max(len(f) for _, f in feeds)
-        if self._chunk_prefill:
-            S = min(self.scfg.max_len, 1 << (S - 1).bit_length())  # bucket: fewer recompiles
-            counts = np.zeros((B,), np.int32)
-            if self._embeds_in:
-                buf = np.zeros((B, S, self.cfg.d_model), np.float32)
-            else:
-                buf = np.zeros((B, S), np.int32)
-            for i, feed in feeds:
-                buf[i, : len(feed)] = feed
-                counts[i] = len(feed)
-            batch = ({"embeds": jnp.asarray(buf)} if self._embeds_in
-                     else {"tokens": jnp.asarray(buf)})
-            _, self.state = self.prefill_fn(self.params, self.state, batch,
-                                            jnp.asarray(counts))
-            for i, feed in feeds:
-                self.ledger.advance(i, len(feed))
-            return
-        for t in range(S):
+        with TraceAnnotation("engine.decode_launch") as span:
             counts = np.zeros((B,), np.int32)
             if self._embeds_in:
                 buf = np.zeros((B, 1, self.cfg.d_model), np.float32)
             else:
                 buf = np.zeros((B, 1), np.int32)
-            for i, feed in feeds:
-                if t < len(feed):
-                    buf[i, 0] = feed[t]
-                    counts[i] = 1
-                    self.ledger.advance(i, 1)
+            rows = 0
+            for i, slot in enumerate(self.slots):
+                if slot.request_id is None:
+                    continue
+                rows += 1
+                counts[i] = 1
+                if self._embeds_in:
+                    buf[i, 0] = (slot.next_embed if slot.next_embed is not None
+                                 else self._featurize([slot.tokens[-1]])[0])
+                else:
+                    buf[i, 0] = slot.tokens[-1]
+            span.set_metadata(rows=rows)
             batch = ({"embeds": jnp.asarray(buf)} if self._embeds_in
                      else {"tokens": jnp.asarray(buf)})
-            _, self.state = self.prefill_fn(self.params, self.state, batch,
-                                            jnp.asarray(counts))
-
-    # ------------------------------------------------------------- decode ----
-    def _decode_once(self) -> None:
-        B = self.scfg.batch_slots
-        counts = np.zeros((B,), np.int32)
-        if self._embeds_in:
-            buf = np.zeros((B, 1, self.cfg.d_model), np.float32)
-        else:
-            buf = np.zeros((B, 1), np.int32)
-        for i, slot in enumerate(self.slots):
-            if slot.request_id is None:
-                continue
-            counts[i] = 1
-            if self._embeds_in:
-                buf[i, 0] = (slot.next_embed if slot.next_embed is not None
-                             else self._featurize([slot.tokens[-1]])[0])
-            else:
-                buf[i, 0] = slot.tokens[-1]
-        batch = ({"embeds": jnp.asarray(buf)} if self._embeds_in
-                 else {"tokens": jnp.asarray(buf)})
-        logits, self.state = self.decode_fn(self.params, self.state, batch,
-                                            jnp.asarray(counts))
-        logits = np.asarray(logits[:, -1, : self.cfg.vocab])  # strip padded vocab
-        for i, slot in enumerate(self.slots):
-            if slot.request_id is None:
-                continue
-            self.ledger.advance(i, 1)
-            if self.scfg.temperature > 0:
-                self._key, sub = jax.random.split(self._key)
-                probs = jax.nn.softmax(jnp.asarray(logits[i]) / self.scfg.temperature)
-                nxt = int(jax.random.categorical(sub, jnp.log(probs + 1e-9)))
-            else:
-                nxt = int(np.argmax(logits[i]))
-            slot.tokens.append(nxt)
-            if self._embeds_in:
-                slot.next_embed = self._featurize([nxt])[0]
-            slot.remaining -= 1
-            if nxt == self.scfg.eos_token or slot.remaining <= 0:
-                self.finished[slot.request_id] = slot.tokens
-                self.ledger.release(i)
-                self.slots[i] = _Slot()
+            logits, self.state = self.decode_fn(self.params, self.state, batch,
+                                                jnp.asarray(counts))
+        with TraceAnnotation("engine.fetch"):
+            logits = np.asarray(logits[:, -1, : self.cfg.vocab])  # strip padded vocab
+        with TraceAnnotation("engine.sample", rows=rows):
+            for i, slot in enumerate(self.slots):
+                if slot.request_id is None:
+                    continue
+                self.ledger.advance(i, 1)
+                if self.scfg.temperature > 0:
+                    self._key, sub = jax.random.split(self._key)
+                    probs = jax.nn.softmax(jnp.asarray(logits[i]) / self.scfg.temperature)
+                    nxt = int(jax.random.categorical(sub, jnp.log(probs + 1e-9)))
+                else:
+                    nxt = int(np.argmax(logits[i]))
+                slot.tokens.append(nxt)
+                if self._embeds_in:
+                    slot.next_embed = self._featurize([nxt])[0]
+                slot.remaining -= 1
+                if nxt == self.scfg.eos_token or slot.remaining <= 0:
+                    self.finished[slot.request_id] = slot.tokens
+                    self.ledger.release(i)
+                    self.slots[i] = _Slot()

@@ -48,6 +48,7 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro.core.p2p import shard_ring_shift_start
 from repro.core.plan import intent_of, ring
@@ -390,15 +391,19 @@ class KVCache(NamedTuple):
 def gqa_attention(p, x, *, n_heads: int, n_kv: int, head_dim: int, rope_theta: float = 10000.0,
                   positions=None, cache: KVCache | None = None, causal: bool = True,
                   attn_impl: str | None = None, block: int = 512, attn_mixed: bool | None = None,
-                  sp_ring_double_buffer: bool = True, new_counts=None, prefill: bool = False):
+                  sp_ring_double_buffer: bool = True, new_counts=None, prefill: bool = False,
+                  layer: tuple = ()):
     """x (B,S,m) -> (B,S,m).  ``cache`` switches to decode mode.
 
     Decode accepts multi-token chunks (S >= 1) and *per-row* state:
     ``positions`` may be (B,S) absolute positions (each slot rotates RoPE and
     masks causally at its own offset) and ``new_counts`` (B,) says how many
     of the chunk's S tokens are valid per row — the per-request extents of
-    continuous batching.  Rows advance their cache length by their own count;
-    the caller masks cache writes of count-0 rows (see
+    continuous batching.  Rows advance their cache length by their own count,
+    and count-0 rows keep their cache bytes (:func:`write_positions`).
+    ``cache.k``/``cache.v`` may be stacked over layers, with ``layer`` the
+    leading indices of this layer: the new K/V are written into the stack in
+    place and the returned cache holds the whole stack (see
     ``repro.models.lm.decode_step``).  ``prefill=True`` marks a whole-prompt
     chunk whose active rows all start at position 0; under an ``sp_ring``
     recipe that chunk runs the ring-attention plan (sequence-parallel batched
@@ -424,10 +429,14 @@ def gqa_attention(p, x, *, n_heads: int, n_kv: int, head_dim: int, rope_theta: f
     recipe = current_recipe()
     if cache is not None:
         adv = S if new_counts is None else new_counts
-        kc = shard_act(_cache_update(cache.k, k, cache.length), "cache_kv")
-        vc = shard_act(_cache_update(cache.v, v, cache.length), "cache_kv")
+        active = None if new_counts is None else new_counts > 0
+        start = cache.length % cache.k.shape[-2]
+        ks = write_positions(cache.k, k, layer, start, active, axis=1)
+        vs = write_positions(cache.v, v, layer, start, active, axis=1)
+        kc = shard_act(layer_view(ks, layer), "cache_kv")
+        vc = shard_act(layer_view(vs, layer), "cache_kv")
         new_len = cache.length + adv
-        new_cache = KVCache(kc, vc, new_len)
+        new_cache = KVCache(ks, vs, new_len)
         if prefill and _ring_applicable(recipe, q, k):
             # whole-prompt prefill chunk: active rows start at position 0, so
             # the chunk's causal attention IS full attention over the prompt
@@ -469,21 +478,46 @@ def gqa_attention(p, x, *, n_heads: int, n_kv: int, head_dim: int, rope_theta: f
     return shard_act(jnp.einsum("bhsd,hdm->bsm", o, p["wo"].astype(x.dtype)), "hidden"), None
 
 
-def _cache_update(cache, new, length):
-    """Insert S new steps at each row's *own* position ``length[b]``.
+def write_positions(stack, new, layer, start, active, *, axis: int):
+    """Write each row's S new positions into one layer of a cache leaf.
 
-    Per-row writes (vmapped ``dynamic_update_slice``) are what make
-    continuous batching sound: slots sit at different sequence positions, so
-    a shared write offset would clobber resident requests' K/V (the old
-    ``length[0]`` bug).  Writes land at ``length[b] % cache_size``: a no-op
-    modulo for full-length caches and ring-buffer semantics for windowed
-    caches (Zamba2 long-context)."""
-    size = cache.shape[2]
+    ``stack`` is (*L, B, *row): the leaf stacked over layers, ``layer`` the
+    leading indices of this layer (``()`` for a leaf of one layer).  ``new``
+    is (B, *chunk), with the chunk's S positions on ``axis`` of a row.  Row
+    ``b`` lands at ``start[b]`` on that axis: its own position, so slots at
+    different lengths never clobber each other, and ``length % size`` gives
+    a windowed cache its ring buffer.
 
-    def row(c, n, p):
-        return jax.lax.dynamic_update_slice(c, n, (0, p, 0))
+    One ``dynamic_update_slice`` per row, unrolled over the static batch:
+    XLA then updates a donated (or scan-carried) stack in place.  A vmapped
+    update, a scatter or a scan over rows makes it copy the whole stack.
+    Rows with ``active[b]`` False write back the S positions they already
+    hold, read at the same start; both ops clamp a start that would run past
+    the end alike, so such a row keeps its bytes even then.
 
-    return jax.vmap(row)(cache, new.astype(cache.dtype), length % size)
+    The held slice is pinned to the default layout.  Left free, the TPU
+    compiler lays the whole scan-carried stack out to suit that small read
+    (positions major to heads) and converts the donated cache into and out
+    of that layout: two whole copies of it every step."""
+    new = new.astype(stack.dtype)
+    lead = len(layer)
+    for b in range(new.shape[0]):
+        at = [*layer, b] + [0] * (new.ndim - 1)
+        at[lead + 1 + axis] = start[b]
+        upd = new[b].reshape((1,) * (lead + 1) + new.shape[1:])
+        if active is not None:
+            held = jax.lax.dynamic_slice(stack, at, upd.shape)
+            held = with_layout_constraint(held, Layout(tuple(range(held.ndim))))
+            upd = jnp.where(active[b], upd, held)
+        stack = jax.lax.dynamic_update_slice(stack, upd, at)
+    return stack
+
+
+def layer_view(stack, layer):
+    """The one layer ``layer`` (leading indices) of a stacked cache leaf."""
+    for i in layer:
+        stack = jax.lax.dynamic_index_in_dim(stack, i, keepdims=False)
+    return stack
 
 
 # ---------------------------------------------------------------- MLA op ----
@@ -502,7 +536,7 @@ def _rms(x, w, eps=1e-6):
 def mla_attention(p, x, *, n_heads: int, d_nope: int, d_rope: int, d_v: int, rope_theta: float = 10000.0,
                   positions=None, cache: MLACache | None = None, attn_impl: str | None = None,
                   block: int = 512, attn_mixed: bool | None = None, new_counts=None,
-                  prefill: bool = False):
+                  prefill: bool = False, layer: tuple = ()):
     """Multi-head Latent Attention (MiniCPM3/DeepSeek-V2 style).
 
     Train/prefill: decompress per-head K/V and run flash attention.
@@ -515,7 +549,7 @@ def mla_attention(p, x, *, n_heads: int, d_nope: int, d_rope: int, d_v: int, rop
     mask cache slot ``t`` to ``t <= positions[b, j]``, which makes a
     whole-prompt chunk exact causal prefill straight through the latent
     cache, so ``prefill`` needs no separate branch here (accepted for API
-    symmetry)."""
+    symmetry).  ``layer`` indexes a cache stacked over layers, as there."""
     B, S, _ = x.shape
     cq = _rms(jnp.einsum("bsm,mq->bsq", x, p["wdq"].astype(x.dtype)), p["q_norm"])
     q = jnp.einsum("bsq,qhc->bhsc", cq, p["wuq"].astype(x.dtype))
@@ -540,9 +574,13 @@ def mla_attention(p, x, *, n_heads: int, d_nope: int, d_rope: int, d_v: int, rop
 
     # ---- absorbed decode ----
     adv = S if new_counts is None else new_counts
-    cc = shard_act(_seq_cache_update(cache.c, c, cache.length), "cache_mla")
-    krc = shard_act(_seq_cache_update(cache.kr, kr, cache.length), "cache_mla")
-    new_cache = MLACache(cc, krc, cache.length + adv)
+    active = None if new_counts is None else new_counts > 0
+    start = cache.length % cache.c.shape[-2]
+    cs = write_positions(cache.c, c, layer, start, active, axis=0)
+    krs = write_positions(cache.kr, kr, layer, start, active, axis=0)
+    cc = shard_act(layer_view(cs, layer), "cache_mla")
+    krc = shard_act(layer_view(krs, layer), "cache_mla")
+    new_cache = MLACache(cs, krs, cache.length + adv)
     # absorb W_uk into q: q_abs (B,H,1,k_rank)
     q_abs = jnp.einsum("bhsn,khn->bhsk", q_nope, p["wuk"].astype(x.dtype))
     scale = (d_nope + d_rope) ** -0.5
@@ -569,17 +607,6 @@ def _pad_last(v, d: int):
         return v
     pad = [(0, 0)] * (v.ndim - 1) + [(0, d - v.shape[-1])]
     return jnp.pad(v, pad)
-
-
-def _seq_cache_update(cache, new, length):
-    """Per-row seq-dim cache insert (MLA latent / rope-key caches): row ``b``
-    writes at its own ``length[b]`` — see :func:`_cache_update`."""
-    size = cache.shape[1]
-
-    def row(c, n, p):
-        return jax.lax.dynamic_update_slice(c, n, (p,) + (0,) * (c.ndim - 1))
-
-    return jax.vmap(row)(cache, new.astype(cache.dtype), length % size)
 
 
 # ------------------------------------------------------- cross-attention ----

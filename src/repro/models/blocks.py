@@ -48,18 +48,20 @@ def attn_block_specs(cfg) -> dict:
     return s
 
 
-def attn_block(p, x, cfg, *, cache=None, positions=None, new_counts=None, prefill=False):
+def attn_block(p, x, cfg, *, cache=None, positions=None, new_counts=None, prefill=False,
+               layer=()):
     """Pre-norm attention + FFN. Returns (x, new_cache, aux_loss).
 
     ``new_counts``/``prefill`` thread the continuous-batching chunk metadata
     to :func:`repro.models.attention.gqa_attention` (per-row valid token
-    counts; whole-prompt prefill chunk)."""
+    counts; whole-prompt prefill chunk), ``layer`` the layer's index into a
+    stacked cache."""
     h, new_cache = attn.gqa_attention(
         p["attn"], pin(rmsnorm(p["ln1"], x)),
         n_heads=cfg.n_heads, n_kv=cfg.n_kv, head_dim=cfg.head_dim,
         rope_theta=cfg.rope_theta, positions=positions, cache=cache,
         attn_impl=cfg.attn_impl, block=cfg.attn_block, attn_mixed=cfg.attn_mixed,
-        new_counts=new_counts, prefill=prefill,
+        new_counts=new_counts, prefill=prefill, layer=layer,
     )
     x = pin(x + h)
     aux = jnp.zeros((), jnp.float32)
@@ -87,13 +89,14 @@ def mla_block_specs(cfg) -> dict:
     }
 
 
-def mla_block(p, x, cfg, *, cache=None, positions=None, new_counts=None, prefill=False):
+def mla_block(p, x, cfg, *, cache=None, positions=None, new_counts=None, prefill=False,
+              layer=()):
     h, new_cache = attn.mla_attention(
         p["attn"], rmsnorm(p["ln1"], x),
         n_heads=cfg.n_heads, d_nope=cfg.mla_d_nope, d_rope=cfg.mla_d_rope, d_v=cfg.mla_d_v,
         rope_theta=cfg.rope_theta, positions=positions, cache=cache,
         attn_impl=cfg.attn_impl, block=cfg.attn_block, attn_mixed=cfg.attn_mixed,
-        new_counts=new_counts, prefill=prefill,
+        new_counts=new_counts, prefill=prefill, layer=layer,
     )
     x = x + h
     f = ffn_mod.swiglu(p["ffn"], rmsnorm(p["ln2"], x))
@@ -206,7 +209,8 @@ def shared_lora_specs(cfg, rank: int = 8) -> dict:
     }
 
 
-def shared_attn_block(p_shared, p_lora, x, cfg, *, cache=None, positions=None, window: int | None = None):
+def shared_attn_block(p_shared, p_lora, x, cfg, *, cache=None, positions=None, window: int | None = None,
+                      new_counts=None, layer=()):
     """Shared-weight attention block with per-application LoRA input adapter.
 
     ``window`` (if set) restricts attention to a trailing window — the
@@ -217,6 +221,7 @@ def shared_attn_block(p_shared, p_lora, x, cfg, *, cache=None, positions=None, w
         n_heads=cfg.n_heads, n_kv=cfg.n_kv, head_dim=cfg.head_dim,
         rope_theta=cfg.rope_theta, positions=positions, cache=cache,
         attn_impl=cfg.attn_impl, block=cfg.attn_block, attn_mixed=cfg.attn_mixed,
+        new_counts=new_counts, layer=layer,
     )
     x = x + h
     f = ffn_mod.swiglu(p_shared["ffn"], rmsnorm(p_shared["ln2"], x))

@@ -280,12 +280,12 @@ def init_cache(cfg, batch_size: int, max_len: int):
 
 
 def _mask_rows(new, old, active):
-    """Restore batch rows ``active[b] == False`` of a cache/state pytree to
-    their pre-step values.  Continuous batching runs the full batch through
-    every step even when some slots carry no valid tokens — their cache
-    writes (and any length advance) are garbage and must not persist.  Every
-    leaf is (B, ...) inside the layer scans, so a broadcast ``where`` on the
-    leading dim is the whole merge."""
+    """Restore batch rows ``active[b] == False`` of a recurrent state pytree
+    to their pre-step values.  Continuous batching runs the full batch
+    through every step even when some slots carry no valid tokens — their
+    state updates are garbage and must not persist.  Every leaf is (B, ...)
+    inside the layer scans, so a broadcast ``where`` on the leading dim is
+    the whole merge."""
     if active is None:
         return new
 
@@ -293,6 +293,40 @@ def _mask_rows(new, old, active):
         return jnp.where(active.reshape((-1,) + (1,) * (n.ndim - 1)), n, o)
 
     return jax.tree.map(leaf, new, old)
+
+
+def _decode_stack(block, stacked, caches, x, active, lead=()):
+    """Run one homogeneous run of blocks over its caches:
+    ``block(p, x, cache, layer) -> (x, new_cache, aux)``.
+
+    The kind of cache decides how it moves.  Length-indexed caches
+    (``KVCache``, ``MLACache``) carry their stacked payloads in the scan and
+    each layer writes only its new positions into them, in place
+    (:func:`repro.models.attention.write_positions`, which also keeps
+    inactive rows' bytes); ``length`` stays per layer in the scanned inputs
+    and outputs.  Their payloads may be stacked over more leading dims than
+    this run: ``lead`` indexes them (the VLM's self-attention runs inside
+    its groups, which are a run of their own).  Recurrent states (RWKV,
+    Mamba) are rewritten whole every step: they pass through the scan as
+    inputs and outputs, with inactive rows merged back (:func:`_mask_rows`)."""
+    if isinstance(caches, (attn_mod.KVCache, attn_mod.MLACache)):
+        def body(carry, layer):
+            x, stacks = carry
+            p, length, i = layer
+            x, c, _ = block(p, x, stacks._replace(length=length), lead + (i,))
+            return (x, c._replace(length=None)), c.length
+
+        n = caches.length.shape[0]
+        (x, stacks), length = jax.lax.scan(
+            body, (x, caches._replace(length=None)), (stacked, caches.length, jnp.arange(n)))
+        return x, stacks._replace(length=length)
+
+    def body(x, layer):
+        p, c = layer
+        x, new_c, _ = block(p, x, c, None)
+        return x, _mask_rows(new_c, c, active)
+
+    return jax.lax.scan(body, x, (stacked, caches))
 
 
 def decode_step(params, state: DecodeState, batch, cfg, *, new_counts=None,
@@ -307,10 +341,11 @@ def decode_step(params, state: DecodeState, batch, cfg, *, new_counts=None,
         (``state.positions[b]``) — RoPE/sinusoidal offsets and causal masks
         are per-row;
       * ``new_counts`` (B,) int32 marks how many of the chunk's S tokens are
-        valid per row (0 = the slot is idle this step).  Idle rows' cache
-        writes are fully masked out (:func:`_mask_rows`) and their positions
-        do not advance — the fix for the cross-slot clobbering bug where one
-        slot's prefill wrote garbage K/V into every resident request's cache;
+        valid per row (0 = the slot is idle this step).  Idle rows keep
+        their cache bytes and state (:func:`_decode_stack`) and their
+        positions do not advance — the fix for the cross-slot clobbering bug
+        where one slot's prefill wrote garbage K/V into every resident
+        request's cache;
       * ``prefill=True`` marks a whole-prompt chunk whose active rows start
         at position 0 (admission-time batched prefill); under an ``sp_ring``
         recipe the attention families run the chunk through the
@@ -319,6 +354,8 @@ def decode_step(params, state: DecodeState, batch, cfg, *, new_counts=None,
     Rows may leave garbage *beyond* their valid count inside the cache
     capacity — sound for non-windowed caches because the next write starts
     at ``length + count`` and the attention mask never reads past ``length``.
+    The length-indexed caches are written in place: a step moves the
+    positions it produces, not the cache.
     """
     positions = state.positions
     S = (batch["embeds"] if cfg.input_kind == "embeds" else batch["tokens"]).shape[1]
@@ -328,79 +365,59 @@ def decode_step(params, state: DecodeState, batch, cfg, *, new_counts=None,
     x = embed_inputs(params, batch, cfg, positions=pos2d)
     fam = cfg.family
     caches = state.caches
+    kw = dict(positions=pos2d, new_counts=new_counts, prefill=prefill)
+
+    def attn_block(p, x, c, layer):
+        return blk.attn_block(p, x, cfg, cache=c, layer=layer, **kw)
+
+    def mamba_block(p, x, c, _):
+        return blk.mamba_block(p, x, cfg, state=c)
 
     if fam in ("dense", "moe", "audio"):
-        def body(x, layer):
-            p, c = layer
-            x, new_c, _ = blk.attn_block(p, x, cfg, cache=c, positions=pos2d,
-                                         new_counts=new_counts, prefill=prefill)
-            return x, _mask_rows(new_c, c, active)
-
-        x, new_caches = jax.lax.scan(body, x, (params["blocks"], caches))
+        x, new_caches = _decode_stack(attn_block, params["blocks"], caches, x, active)
     elif fam == "mla":
-        def body(x, layer):
-            p, c = layer
-            x, new_c, _ = blk.mla_block(p, x, cfg, cache=c, positions=pos2d,
-                                        new_counts=new_counts, prefill=prefill)
-            return x, _mask_rows(new_c, c, active)
+        def mla_block(p, x, c, layer):
+            return blk.mla_block(p, x, cfg, cache=c, layer=layer, **kw)
 
-        x, new_caches = jax.lax.scan(body, x, (params["blocks"], caches))
+        x, new_caches = _decode_stack(mla_block, params["blocks"], caches, x, active)
+    elif fam == "ssm":
+        def rwkv_block(p, x, c, _):
+            return blk.rwkv_block(p, x, cfg, state=c)
+
+        x, new_caches = _decode_stack(rwkv_block, params["blocks"], caches, x, active)
     elif fam == "vlm":
         enc = shard_act(batch["image_embeds"], "enc")
 
-        def group(x, layer):
-            (p_self, p_cross), c_self = layer
+        def group(p, x, c, layer):
+            p_self, p_cross = p
+            x, c = _decode_stack(attn_block, p_self, c, x, active, lead=layer)
+            return blk.cross_block(p_cross, x, enc, cfg), c, None
 
-            def body(x, sl):
-                p, c = sl
-                x, new_c, _ = blk.attn_block(p, x, cfg, cache=c, positions=pos2d,
-                                             new_counts=new_counts, prefill=prefill)
-                return x, _mask_rows(new_c, c, active)
-
-            x, new_c_self = jax.lax.scan(body, x, (p_self, c_self))
-            x = blk.cross_block(p_cross, x, enc, cfg)
-            return x, new_c_self
-
-        x, new_self = jax.lax.scan(
-            group, x, ((params["self_blocks"], params["cross_blocks"]), caches["self"])
-        )
-        new_caches = {"self": new_self}
-    elif fam == "ssm":
-        def body(x, layer):
-            p, c = layer
-            x, new_c, _ = blk.rwkv_block(p, x, cfg, state=c)
-            return x, _mask_rows(new_c, c, active)
-
-        x, new_caches = jax.lax.scan(body, x, (params["blocks"], caches))
+        x, c_self = _decode_stack(group, (params["self_blocks"], params["cross_blocks"]),
+                                  caches["self"], x, active)
+        new_caches = {"self": c_self}
     elif fam == "hybrid":
-        def group(x, layer):
-            (p_mamba, p_lora), (c_mamba, c_shared) = layer
+        shared = caches["shared"]
 
-            def body(x, ml):
-                p, c = ml
-                x, new_c, _ = blk.mamba_block(p, x, cfg, state=c)
-                return x, _mask_rows(new_c, c, active)
-
-            x, new_c_mamba = jax.lax.scan(body, x, (p_mamba, c_mamba))
-            x, new_c_shared, _ = blk.shared_attn_block(
-                params["shared_block"], p_lora, x, cfg, cache=c_shared,
-                positions=pos2d, window=cfg.shared_window,
+        def group(carry, layer):
+            x, stacks = carry
+            (p_mamba, p_lora), c_mamba, length, g = layer
+            x, new_c_mamba = _decode_stack(mamba_block, p_mamba, c_mamba, x, active)
+            x, c, _ = blk.shared_attn_block(
+                params["shared_block"], p_lora, x, cfg, cache=stacks._replace(length=length),
+                positions=pos2d, window=cfg.shared_window, new_counts=new_counts, layer=(g,),
             )
-            return x, (new_c_mamba, _mask_rows(new_c_shared, c_shared, active))
+            return (x, c._replace(length=None)), (new_c_mamba, c.length)
 
-        x, (new_mamba, new_shared) = jax.lax.scan(
-            group, x,
-            ((params["mamba_blocks"], params["shared_lora"]), (caches["mamba"], caches["shared"])),
+        (x, stacks), (new_mamba, length) = jax.lax.scan(
+            group, (x, shared._replace(length=None)),
+            ((params["mamba_blocks"], params["shared_lora"]), caches["mamba"], shared.length,
+             jnp.arange(shared.length.shape[0])),
         )
-        new_caches = {"mamba": new_mamba, "shared": new_shared}
+        new_caches = {"mamba": new_mamba, "shared": stacks._replace(length=length)}
         if "tail" in caches:
-            def body(x, ml):
-                p, c = ml
-                x, new_c, _ = blk.mamba_block(p, x, cfg, state=c)
-                return x, _mask_rows(new_c, c, active)
-
-            x, new_tail = jax.lax.scan(body, x, (params["tail_blocks"], caches["tail"]))
-            new_caches["tail"] = new_tail
+            x, new_caches["tail"] = _decode_stack(mamba_block, params["tail_blocks"],
+                                                  caches["tail"], x, active)
     else:
         raise ValueError(fam)
 

@@ -15,7 +15,7 @@ picture, applied to cache residency).
 
 The ledger is bookkeeping only: the cache buffers themselves advance their
 per-row ``length`` inside the jitted step (see
-``repro.models.attention._cache_update``); the ledger mirrors those lengths
+``repro.models.attention.write_positions``); the ledger mirrors those lengths
 on the host, where admission decisions are made.
 """
 from __future__ import annotations

@@ -81,3 +81,118 @@ def test_decode_matches_forward(arch):
         rtol=tol, atol=tol,
         err_msg=f"{arch}: cache decode diverges from full forward",
     )
+
+
+# ---------------------------------------------------- in-place cache writes ----
+# The engine's step programs write each row's new positions into the donated
+# stacked cache in place (attention.write_positions).  Length-indexed cache
+# leaves, by name; recurrent states (ssm, conv, wkv, shift) are rewritten
+# whole every step and are not covered here.
+_CACHE_LEAVES = ("k", "v", "c", "kr")
+
+
+def _engine(arch, params, B, T):
+    from repro.serve.engine import Engine, ServeConfig
+
+    cfg = configs.get(arch, smoke=True)
+    return Engine(cfg, params, ServeConfig(max_len=T, batch_slots=B, eos_token=-1)), cfg
+
+
+def _step_args(cfg, B, S, abstract=False):
+    if cfg.input_kind == "embeds":
+        batch = {"embeds": jnp.zeros((B, S, cfg.d_model), jnp.float32)}
+    else:
+        batch = {"tokens": jnp.zeros((B, S), jnp.int32)}
+    if cfg.input_kind == "tokens+image":
+        batch["image_embeds"] = jnp.zeros((B, cfg.enc_len, cfg.enc_dim), jnp.float32)
+    if abstract:
+        batch = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), batch)
+    return batch
+
+
+def _cache_leaves(caches):
+    for path, leaf in jax.tree_util.tree_flatten_with_path(caches)[0]:
+        name = getattr(path[-1], "name", "")
+        if name in _CACHE_LEAVES:
+            yield jax.tree_util.keystr(path), leaf
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("arch", ["phi4-mini-3.8b", "minicpm3-4b", "zamba2-7b",
+                                  "llama-3.2-vision-11b"])
+def test_step_programs_do_not_copy_the_cache(arch, program):
+    """The compiled decode and prefill programs hold no copy of a stacked
+    cache leaf: a layer scan that passes the cache through its inputs and
+    outputs copies the whole stack every step."""
+    import re
+
+    B, T = 8, 128
+    cfg = configs.get(arch, smoke=True)
+    params = jax.eval_shape(lambda: lm.init_model(cfg, jax.random.PRNGKey(0)))
+    eng, cfg = _engine(arch, params, B, T)
+    state = jax.eval_shape(lambda: eng.state)
+    stacked = {tuple(leaf.shape) for _, leaf in _cache_leaves(state.caches)}
+    assert stacked
+    fn, S = (eng.decode_fn, 1) if program == "decode" else (eng.prefill_fn, 64)
+    counts = jax.ShapeDtypeStruct((B,), jnp.int32)
+    hlo = fn.lower(params, state, _step_args(cfg, B, S, abstract=True), counts).compile().as_text()
+    copies = [line.strip() for line in hlo.splitlines()
+              if (m := re.search(r"= \w+\[([\d,]*)\]\{[^}]*\} copy\(", line))
+              and tuple(int(d) for d in m.group(1).split(",") if d) in stacked]
+    assert not copies, copies
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("arch", ["phi4-mini-3.8b", "minicpm3-4b", "zamba2-7b"])
+def test_inactive_rows_keep_their_cache(arch, program):
+    """Rows with count 0 keep every cache byte through a step, also where a
+    prefill chunk's start is clamped (a resident row's length + S runs past
+    the cache's end), and active rows change only at the positions they
+    write.  Smoke zamba2's shared cache is a 64-slot ring (max_len 96), and
+    one of its resident rows has wrapped."""
+    B, T = 4, 96
+    cfg = configs.get(arch, smoke=True)
+    eng, cfg = _engine(arch, lm.init_model(cfg, jax.random.PRNGKey(0)), B, T)
+    lengths = np.array([80, 5, 40, 0], np.int32)
+    if program == "decode":
+        S, counts = 1, np.array([1, 0, 1, 0], np.int32)
+        fn = eng.decode_fn
+    else:
+        S, counts = 32, np.array([0, 0, 20, 32], np.int32)  # rows 0, 1 resident
+        fn = eng.prefill_fn
+    key = jax.random.PRNGKey(1)
+
+    def fill(path, leaf):
+        name = getattr(path[-1], "name", "")
+        if name == "length":
+            return jnp.broadcast_to(jnp.asarray(lengths), leaf.shape)
+        if name in _CACHE_LEAVES:
+            return jax.random.normal(jax.random.fold_in(key, leaf.size), leaf.shape).astype(leaf.dtype)
+        return leaf
+
+    caches = jax.tree_util.tree_map_with_path(fill, eng.state.caches)
+    before = {p: np.asarray(x) for p, x in _cache_leaves(caches)}
+    assert before
+    state = lm.DecodeState(caches=caches, positions=jnp.asarray(lengths))
+    batch = _step_args(cfg, B, S)
+    batch = jax.tree.map(lambda x: x + 3 if x.dtype == jnp.int32 else x + 0.5, batch)
+    _, new = fn(eng.params, state, batch, jnp.asarray(counts))
+    after = {p: np.asarray(x) for p, x in _cache_leaves(new.caches)}
+    for path, old in before.items():
+        # leaves are (*layers, B, G, T, D) for k/v and (*layers, B, T, R) for
+        # c/kr: positions are the second-to-last axis in both
+        batch_axis = old.ndim - (4 if path.endswith((".k", ".v")) else 3)
+        size = old.shape[-2]
+        for b in range(B):
+            row_old = np.take(old, b, axis=batch_axis)
+            row_new = np.take(after[path], b, axis=batch_axis)
+            if not counts[b]:
+                assert np.array_equal(row_old, row_new), (path, b)
+                continue
+            start = min(int(lengths[b]) % size, size - S)
+            keep = np.ones(size, bool)
+            keep[start:start + S] = False
+            assert np.array_equal(np.compress(keep, row_old, axis=-2),
+                                  np.compress(keep, row_new, axis=-2)), (path, b)
+            assert not np.array_equal(row_old, row_new), (path, b)
+    np.testing.assert_array_equal(np.asarray(new.positions), lengths + counts)

@@ -171,3 +171,36 @@ def test_tp_decode_reductions_stay_apart_for_v5e(topo, no_cache, S):
     assert len(reductions) == 1 + L * 2 * mb
     assert not [r for r in reductions if r.startswith("(")], "tuple all-reduce"
     assert "tpu_custom_call" in txt
+
+
+@pytest.mark.parametrize("S", [1, 1024], ids=["decode", "prefill_chunk"])
+@pytest.mark.parametrize("arch", ["phi4-mini-3.8b", "minicpm3-4b"])
+def test_engine_steps_update_the_cache_in_place_for_v5e(one_chip, no_cache, arch, S):
+    """The engine's step programs at full widths (depth cut to 2 layers; 8
+    slots x 2048 positions) write the stacked cache in place: no copy of a
+    stacked cache leaf, and the leaves keep the layout the donated buffers
+    arrive in.  A layer scan that passes the cache through its inputs and
+    outputs copies it whole; a carried stack laid out to suit the masked
+    rows' read is converted in and out.  MLA's 32-wide rope-key cache is
+    left out: the compiler lays it out positions-minor on the device and
+    re-lays it for the loop, with or without the mask."""
+    from repro.models import lm
+    from repro.serve.engine import Engine, ServeConfig
+
+    slots, T = 8, 2048
+    cfg = dataclasses.replace(configs.get(arch), param_dtype=jnp.bfloat16, n_layers=2,
+                              attn_impl="pallas")
+    on = lambda tree: jax.tree.map(lambda x: _arg(one_chip, x.shape, x.dtype), tree)
+    params = on(jax.eval_shape(lambda: lm.init_model(cfg, jax.random.PRNGKey(0))))
+    eng = Engine(cfg, params, ServeConfig(max_len=T, batch_slots=slots))
+    state = on(jax.eval_shape(lambda: eng.state))
+    fn = eng.decode_fn if S == 1 else eng.prefill_fn
+    txt = fn.lower(params, state, {"tokens": _arg(one_chip, (slots, S), jnp.int32)},
+                   _arg(one_chip, (slots,), jnp.int32)).compile().as_text()
+    leaves = [state.caches.k, state.caches.v] if arch.startswith("phi4") else [state.caches.c]
+    for leaf in leaves:
+        shape = re.escape(",".join(map(str, leaf.shape)))
+        default = ",".join(map(str, reversed(range(leaf.ndim))))
+        layouts = set(re.findall(rf"bf16\[{shape}\]\{{([\d,]+)", txt))
+        assert layouts == {default}, (leaf.shape, layouts)
+        assert not re.search(rf"= bf16\[{shape}\]\{{[^}}]*\}} copy\(", txt), leaf.shape

@@ -15,6 +15,11 @@ Orientation encoding (matching the paper's x-axis labels):
 
 ('major' = the OUTER buffer axis, i.e. the slower-varying one.)
 
+Precision: the operand dtype decides it. Float32 operands are multiplied at
+float32 precision (``lax.Precision.HIGHEST``, which Mosaic lowers to a
+float32-precision ``tpu.matmul``; the v5e's MXU computes it in several bf16
+passes), bf16 operands in the MXU's single bf16 pass. Both accumulate in f32.
+
 VMEM budget: one (bm, bk) A tile + one (bk, bn) B tile + one (bm, bn) f32
 accumulator.  Defaults bm=bn=bk=256 in f32: 3*256*256*4 B = 768 KiB << 16 MiB
 VMEM; MXU dims are multiples of 128.
@@ -35,6 +40,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 
 __all__ = ["gemm_pallas", "gemm_panel_pallas"]
@@ -63,7 +69,10 @@ def _gemm_kernel(a_ref, b_ref, *refs, a_trans: bool, b_trans: bool, c_trans: boo
     b = b_ref[...]
     if b_trans:
         b = b.T
-    acc_ref[...] += jnp.dot(a, b, preferred_element_type=jnp.float32)
+    # float32 tiles are multiplied at float32 precision: at the default
+    # precision the MXU would take them in one bf16 pass
+    precision = lax.Precision.HIGHEST if a.dtype == b.dtype == jnp.float32 else None
+    acc_ref[...] += jnp.dot(a, b, precision=precision, preferred_element_type=jnp.float32)
 
     @pl.when(k == nk - 1)
     def _store():

@@ -133,6 +133,51 @@ def test_gemm_panel_compiles_for_v5e(one_chip, no_cache):
     assert "tpu_custom_call" in txt
 
 
+def _mosaic_matmuls(lowered_text):
+    """The ``tpu.matmul`` operations of every Mosaic kernel in a lowered
+    program, as MLIR text: each kernel's body rides in its custom call's
+    backend config as base64 MLIR bytecode."""
+    import base64
+
+    from jax._src.lib.mlir import ir
+
+    found = []
+    for body in re.findall(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', lowered_text):
+        ctx = ir.Context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            text = str(ir.Module.parse(base64.b64decode(body)))
+        found += [ln.strip() for ln in text.splitlines() if "tpu.matmul" in ln]
+    return found
+
+
+def test_gemm_panel_float32_products_for_v5e(one_chip, no_cache):
+    """The SUMMA inner step at the summa-xl-2x2 cell's tile shapes
+    (PolyBench EXTRALARGE on a 2x2 grid: mi=1024, kc=704, jr=1280, blocks
+    256 x 704 x 256 as ``summa_ring_program`` picks them) compiles with
+    float32 operands, and its dot is one float32-precision matmul of the
+    float32 tiles, not a product of operands rounded to bf16. bf16 operands
+    keep the single bf16 pass."""
+    from examples.distributed_gemm import _tile_block
+
+    mi, kc, jr = 1024, 704, 1280
+    blocks = dict(bm=_tile_block(mi), bn=_tile_block(jr), bk=_tile_block(kc))
+    assert blocks == dict(bm=256, bn=256, bk=704)
+    fn = jax.jit(lambda a, b, panel, jb: gemm_panel_pallas(a, b, panel, jb, **blocks))
+    for dtype, precision in ((jnp.float32, "precision = #tpu.contract_precision<fp32>"),
+                             (jnp.bfloat16, None)):
+        lowered = fn.lower(_arg(one_chip, (mi, kc), dtype), _arg(one_chip, (kc, jr), dtype),
+                           _arg(one_chip, (mi, 2 * jr), jnp.float32), _arg(one_chip, (), jnp.int32))
+        assert "tpu_custom_call" in lowered.compile().as_text()
+        (matmul,) = _mosaic_matmuls(lowered.as_text())
+        name = jnp.dtype(dtype).name.replace("float", "f")  # f32, bf16
+        assert re.search(rf"\(vector<256x704x{name}>, vector<704x256x{name}>", matmul), matmul
+        if precision:
+            assert precision in matmul, matmul
+        else:
+            assert "precision" not in matmul, matmul
+
+
 @pytest.mark.parametrize("S", [1, 1024], ids=["decode", "prefill_chunk"])
 def test_tp_decode_reductions_stay_apart_for_v5e(topo, no_cache, S):
     """The explicit TP step at phi4-mini widths (depth cut to 2 layers) on a

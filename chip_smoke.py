@@ -12,15 +12,16 @@ drawn from ``--seed`` is served by the continuous-batching engine
 prompts of 128-1024 tokens and 32 new tokens each, on 8 slots, so admission
 runs again when slots free up.  Checks: every request ends with 32 tokens;
 every step's logits are finite; the compiled decode step holds the Pallas
-kernel (``tpu_custom_call``); and the engine's first prefill chunk and
-first decode step agree with the same steps built with ``attn_impl="jnp"``.
+kernel (``tpu_custom_call``); and the first admission's prefill (one
+one-row call a slot) and the first decode step agree with the same steps
+built with ``attn_impl="jnp"``.
 
 Four chips (``--four-chips``), one process driving all of them: the paper's
 SUMMA GEMM at PolyBench EXTRALARGE on a 2x2 grid against the host's f32
 ``A @ B``, and the tensor-parallel engine serving full-width phi4-mini on a
-(1, 4) (data, model) mesh against the one-chip engine: its first prefill
-chunk and first decode step, and each device holding only its shard of
-the weights.
+(1, 4) (data, model) mesh against the one-chip engine: its first
+admission's prefill and first decode step, and each device holding only
+its shard of the weights.
 
 The last line of standard output is ``{"ok": true, "device": {...}}``.  It is
 printed only when every phase passed; any failure exits non-zero before it,
@@ -79,35 +80,42 @@ def tpu_devices():
 
 
 class StepRecorder:
-    """Wraps an engine step program ``(params, state, batch, counts) ->
-    (logits, state)``: compiles each new input signature ahead of time and
-    times it, runs the compiled executable, keeps the first call's inputs
-    and logits, and fails on non-finite logits of any active row."""
+    """Wraps an engine step program ``(params, state, batch, counts[, slot])
+    -> (logits, state)``: compiles each new input signature ahead of time
+    and times it, runs the compiled executable, keeps the first ``keep``
+    calls' inputs and the logits of their active rows, and fails on
+    non-finite logits of any active row."""
 
-    def __init__(self, name, fn):
-        self.name, self.fn = name, fn
+    def __init__(self, name, fn, keep: int = 1):
+        self.name, self.fn, self.keep = name, fn, keep
         self.compiled = {}
         self.compile_s = []
-        self.first = None
+        self.kept = []
 
-    def __call__(self, params, state, batch, counts):
+    def __call__(self, params, state, batch, counts, *slot):
         import jax
         import numpy as np
 
-        key = str(jax.tree.map(lambda x: (x.shape, str(x.dtype)), batch))
+        key = str(jax.tree.map(lambda x: (x.shape, str(x.dtype)), (batch, slot)))
         if key not in self.compiled:
             t0 = time.perf_counter()
-            self.compiled[key] = self.fn.lower(params, state, batch, counts).compile()
+            self.compiled[key] = self.fn.lower(params, state, batch, counts, *slot).compile()
             self.compile_s.append(time.perf_counter() - t0)
             log(f"compiled {self.name} for {key}: {self.compile_s[-1]:.1f} s")
-        logits, state = self.compiled[key](params, state, batch, counts)
+        logits, state = self.compiled[key](params, state, batch, counts, *slot)
         active = np.asarray(counts) > 0
         rows = np.asarray(logits[:, -1], np.float32)[active]
         if not np.isfinite(rows).all():
             raise RuntimeError(f"{self.name}: non-finite logits")
-        if self.first is None:
-            self.first = dict(batch=batch, counts=counts, logits=rows)
+        if len(self.kept) < self.keep:
+            self.kept.append(dict(args=(batch, counts, *slot), logits=rows))
         return logits, state
+
+    def logits(self):
+        """The kept calls' logits of their active rows, in call order."""
+        import numpy as np
+
+        return np.concatenate([c["logits"] for c in self.kept])
 
     def hlo(self) -> str:
         return next(iter(self.compiled.values())).as_text()
@@ -159,8 +167,9 @@ def device_bytes(dev, key: str = "peak_bytes_in_use") -> int:
 
 
 def serve_phase(cfg, *, seed: int) -> None:
-    """Serve N_REQUESTS through the engine, then replay its first prefill
-    chunk and first decode step with jnp attention and compare logits."""
+    """Serve N_REQUESTS through the engine, then replay its first
+    admission's prefill calls and first decode step with jnp attention and
+    compare logits."""
     import jax
     import numpy as np
 
@@ -173,7 +182,9 @@ def serve_phase(cfg, *, seed: int) -> None:
         f"{MAX_NEW} new tokens each, {SLOTS} slots")
 
     engine = Engine(cfg, params, scfg)
-    prefill = engine.prefill_fn = StepRecorder("prefill", engine.prefill_fn)
+    # the first admission fills every slot before the first decode step,
+    # one prefill call a slot
+    prefill = engine.prefill_fn = StepRecorder("prefill", engine.prefill_fn, keep=SLOTS)
     decode = engine.decode_fn = StepRecorder("decode", engine.decode_fn)
     for rid, prompt in enumerate(prompts):
         engine.submit(rid, prompt, max_new_tokens=MAX_NEW)
@@ -191,28 +202,20 @@ def serve_phase(cfg, *, seed: int) -> None:
     log(f"peak_bytes_in_use after serving: {device_bytes(jax.devices()[0])}")
     del engine
 
-    # The same two steps with jnp attention, from a fresh cache.  Rows are
-    # independent, so the reference runs half the slots at a time: its
-    # prefill materializes (rows, heads, 1024, 2048) f32 scores, and at all
-    # 8 rows those do not fit beside the weights and the cache.
-    p, d = prefill.first, decode.first
-    ref_cfg = dataclasses.replace(cfg, attn_impl="jnp")
-    half = SLOTS // 2
-    want_p, want_d = [], []
-    for rows in (slice(0, half), slice(half, SLOTS)):
-        ref = Engine(ref_cfg, params, dataclasses.replace(scfg, batch_slots=half))
-        ref_prefill = StepRecorder("prefill (jnp)", ref.prefill_fn)
-        ref_decode = StepRecorder("decode (jnp)", ref.decode_fn)
-        take = lambda tree: jax.tree.map(lambda x: x[rows], tree)
-        _, state = ref_prefill(params, ref.state, take(p["batch"]), take(p["counts"]))
-        ref_decode(params, state, take(d["batch"]), take(d["counts"]))
-        want_p.append(ref_prefill.first["logits"])
-        want_d.append(ref_decode.first["logits"])
-        del ref, state
-    compare("first prefill chunk, pallas vs jnp", p["logits"],
-            np.concatenate(want_p), LOGIT_TOL)
-    compare("first decode step, pallas vs jnp", d["logits"],
-            np.concatenate(want_d), LOGIT_TOL)
+    # The same steps with jnp attention, from a fresh cache: the first
+    # admission's prefill calls replayed in order, then the first decode step.
+    ref = Engine(dataclasses.replace(cfg, attn_impl="jnp"), params, scfg)
+    ref_prefill = StepRecorder("prefill (jnp)", ref.prefill_fn, keep=SLOTS)
+    ref_decode = StepRecorder("decode (jnp)", ref.decode_fn)
+    state = ref.state
+    for call in prefill.kept:
+        _, state = ref_prefill(params, state, *call["args"])
+    ref_decode(params, state, *decode.kept[0]["args"])
+    del ref, state
+    compare("first admission's prefill, pallas vs jnp", prefill.logits(),
+            ref_prefill.logits(), LOGIT_TOL)
+    compare("first decode step, pallas vs jnp", decode.logits(),
+            ref_decode.logits(), LOGIT_TOL)
     log(f"peak_bytes_in_use: {device_bytes(jax.devices()[0])}")
 
 
@@ -240,13 +243,14 @@ def summa_phase() -> None:
 
 def first_steps(engine, label: str, prompts):
     """Admit ``prompts`` and run one engine step; returns the logits of the
-    first prefill chunk and of the first decode step."""
-    prefill = engine.prefill_fn = StepRecorder(f"prefill ({label})", engine.prefill_fn)
+    admission's prefill (every call's active rows, in slot order) and of the
+    first decode step."""
+    prefill = engine.prefill_fn = StepRecorder(f"prefill ({label})", engine.prefill_fn, keep=SLOTS)
     decode = engine.decode_fn = StepRecorder(f"decode ({label})", engine.decode_fn)
     for rid, prompt in enumerate(prompts):
         engine.submit(rid, prompt, max_new_tokens=MAX_NEW)
     engine.run(max_steps=1)
-    return prefill.first["logits"], decode.first["logits"]
+    return prefill.logits(), decode.logits()
 
 
 def tp_decode_phase(cfg, *, seed: int) -> None:
@@ -276,7 +280,7 @@ def tp_decode_phase(cfg, *, seed: int) -> None:
     if max(in_use) > param_bytes / 2:
         raise RuntimeError(f"a device holds {max(in_use)} bytes under TP, more than "
                            f"half the {param_bytes} bytes of weights")
-    compare("first prefill chunk, TP (1, 4) vs one chip", got[0], want[0], TP_LOGIT_TOL)
+    compare("first admission's prefill, TP (1, 4) vs one chip", got[0], want[0], TP_LOGIT_TOL)
     compare("first decode step, TP (1, 4) vs one chip", got[1], want[1], TP_LOGIT_TOL)
 
 

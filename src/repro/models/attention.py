@@ -392,7 +392,7 @@ def gqa_attention(p, x, *, n_heads: int, n_kv: int, head_dim: int, rope_theta: f
                   positions=None, cache: KVCache | None = None, causal: bool = True,
                   attn_impl: str | None = None, block: int = 512, attn_mixed: bool | None = None,
                   sp_ring_double_buffer: bool = True, new_counts=None, prefill: bool = False,
-                  layer: tuple = ()):
+                  layer: tuple = (), slot=None):
     """x (B,S,m) -> (B,S,m).  ``cache`` switches to decode mode.
 
     Decode accepts multi-token chunks (S >= 1) and *per-row* state:
@@ -404,7 +404,9 @@ def gqa_attention(p, x, *, n_heads: int, n_kv: int, head_dim: int, rope_theta: f
     ``cache.k``/``cache.v`` may be stacked over layers, with ``layer`` the
     leading indices of this layer: the new K/V are written into the stack in
     place and the returned cache holds the whole stack (see
-    ``repro.models.lm.decode_step``).  ``prefill=True`` marks a whole-prompt
+    ``repro.models.lm.decode_step``); given ``slot``, the chunk's rows are
+    the cache slots from ``slot`` on and ``cache.length`` holds theirs alone
+    (:func:`write_positions`).  ``prefill=True`` marks a whole-prompt
     chunk whose active rows all start at position 0; under an ``sp_ring``
     recipe that chunk runs the ring-attention plan (sequence-parallel batched
     prefill) while the K/V writes fill the cache.
@@ -431,10 +433,10 @@ def gqa_attention(p, x, *, n_heads: int, n_kv: int, head_dim: int, rope_theta: f
         adv = S if new_counts is None else new_counts
         active = None if new_counts is None else new_counts > 0
         start = cache.length % cache.k.shape[-2]
-        ks = write_positions(cache.k, k, layer, start, active, axis=1)
-        vs = write_positions(cache.v, v, layer, start, active, axis=1)
-        kc = shard_act(layer_view(ks, layer), "cache_kv")
-        vc = shard_act(layer_view(vs, layer), "cache_kv")
+        ks = write_positions(cache.k, k, layer, start, active, axis=1, slot=slot)
+        vs = write_positions(cache.v, v, layer, start, active, axis=1, slot=slot)
+        kc = shard_act(layer_view(ks, layer, slot, B), "cache_kv")
+        vc = shard_act(layer_view(vs, layer, slot, B), "cache_kv")
         new_len = cache.length + adv
         new_cache = KVCache(ks, vs, new_len)
         if prefill and _ring_applicable(recipe, q, k):
@@ -478,15 +480,17 @@ def gqa_attention(p, x, *, n_heads: int, n_kv: int, head_dim: int, rope_theta: f
     return shard_act(jnp.einsum("bhsd,hdm->bsm", o, p["wo"].astype(x.dtype)), "hidden"), None
 
 
-def write_positions(stack, new, layer, start, active, *, axis: int):
+def write_positions(stack, new, layer, start, active, *, axis: int, slot=None):
     """Write each row's S new positions into one layer of a cache leaf.
 
     ``stack`` is (*L, B, *row): the leaf stacked over layers, ``layer`` the
     leading indices of this layer (``()`` for a leaf of one layer).  ``new``
-    is (B, *chunk), with the chunk's S positions on ``axis`` of a row.  Row
-    ``b`` lands at ``start[b]`` on that axis: its own position, so slots at
-    different lengths never clobber each other, and ``length % size`` gives
-    a windowed cache its ring buffer.
+    is (R, *chunk), with the chunk's S positions on ``axis`` of a row.  Row
+    ``b`` goes to cache slot ``b``, or to ``slot + b`` given ``slot`` (a
+    traced int32 scalar: the rows are a run of the B slots).  It lands at
+    ``start[b]`` on that axis: its own position, so slots at different
+    lengths never clobber each other, and ``length % size`` gives a
+    windowed cache its ring buffer.
 
     One ``dynamic_update_slice`` per row, unrolled over the static batch:
     XLA then updates a donated (or scan-carried) stack in place.  A vmapped
@@ -502,7 +506,7 @@ def write_positions(stack, new, layer, start, active, *, axis: int):
     new = new.astype(stack.dtype)
     lead = len(layer)
     for b in range(new.shape[0]):
-        at = [*layer, b] + [0] * (new.ndim - 1)
+        at = [*layer, b if slot is None else slot + b] + [0] * (new.ndim - 1)
         at[lead + 1 + axis] = start[b]
         upd = new[b].reshape((1,) * (lead + 1) + new.shape[1:])
         if active is not None:
@@ -513,10 +517,13 @@ def write_positions(stack, new, layer, start, active, *, axis: int):
     return stack
 
 
-def layer_view(stack, layer):
-    """The one layer ``layer`` (leading indices) of a stacked cache leaf."""
+def layer_view(stack, layer, slot=None, rows: int = 1):
+    """The one layer ``layer`` (leading indices) of a stacked cache leaf;
+    given ``slot``, only its ``rows`` slots from ``slot`` on."""
     for i in layer:
         stack = jax.lax.dynamic_index_in_dim(stack, i, keepdims=False)
+    if slot is not None:
+        stack = jax.lax.dynamic_slice_in_dim(stack, slot, rows)
     return stack
 
 
@@ -536,7 +543,7 @@ def _rms(x, w, eps=1e-6):
 def mla_attention(p, x, *, n_heads: int, d_nope: int, d_rope: int, d_v: int, rope_theta: float = 10000.0,
                   positions=None, cache: MLACache | None = None, attn_impl: str | None = None,
                   block: int = 512, attn_mixed: bool | None = None, new_counts=None,
-                  prefill: bool = False, layer: tuple = ()):
+                  prefill: bool = False, layer: tuple = (), slot=None):
     """Multi-head Latent Attention (MiniCPM3/DeepSeek-V2 style).
 
     Train/prefill: decompress per-head K/V and run flash attention.
@@ -549,7 +556,8 @@ def mla_attention(p, x, *, n_heads: int, d_nope: int, d_rope: int, d_v: int, rop
     mask cache slot ``t`` to ``t <= positions[b, j]``, which makes a
     whole-prompt chunk exact causal prefill straight through the latent
     cache, so ``prefill`` needs no separate branch here (accepted for API
-    symmetry).  ``layer`` indexes a cache stacked over layers, as there."""
+    symmetry).  ``layer`` indexes a cache stacked over layers and ``slot``
+    places the rows in it, as there."""
     B, S, _ = x.shape
     cq = _rms(jnp.einsum("bsm,mq->bsq", x, p["wdq"].astype(x.dtype)), p["q_norm"])
     q = jnp.einsum("bsq,qhc->bhsc", cq, p["wuq"].astype(x.dtype))
@@ -576,10 +584,10 @@ def mla_attention(p, x, *, n_heads: int, d_nope: int, d_rope: int, d_v: int, rop
     adv = S if new_counts is None else new_counts
     active = None if new_counts is None else new_counts > 0
     start = cache.length % cache.c.shape[-2]
-    cs = write_positions(cache.c, c, layer, start, active, axis=0)
-    krs = write_positions(cache.kr, kr, layer, start, active, axis=0)
-    cc = shard_act(layer_view(cs, layer), "cache_mla")
-    krc = shard_act(layer_view(krs, layer), "cache_mla")
+    cs = write_positions(cache.c, c, layer, start, active, axis=0, slot=slot)
+    krs = write_positions(cache.kr, kr, layer, start, active, axis=0, slot=slot)
+    cc = shard_act(layer_view(cs, layer, slot, B), "cache_mla")
+    krc = shard_act(layer_view(krs, layer, slot, B), "cache_mla")
     new_cache = MLACache(cs, krs, cache.length + adv)
     # absorb W_uk into q: q_abs (B,H,1,k_rank)
     q_abs = jnp.einsum("bhsn,khn->bhsk", q_nope, p["wuk"].astype(x.dtype))

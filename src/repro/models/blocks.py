@@ -49,19 +49,19 @@ def attn_block_specs(cfg) -> dict:
 
 
 def attn_block(p, x, cfg, *, cache=None, positions=None, new_counts=None, prefill=False,
-               layer=()):
+               layer=(), slot=None):
     """Pre-norm attention + FFN. Returns (x, new_cache, aux_loss).
 
     ``new_counts``/``prefill`` thread the continuous-batching chunk metadata
     to :func:`repro.models.attention.gqa_attention` (per-row valid token
     counts; whole-prompt prefill chunk), ``layer`` the layer's index into a
-    stacked cache."""
+    stacked cache and ``slot`` the cache slot of the chunk's first row."""
     h, new_cache = attn.gqa_attention(
         p["attn"], pin(rmsnorm(p["ln1"], x)),
         n_heads=cfg.n_heads, n_kv=cfg.n_kv, head_dim=cfg.head_dim,
         rope_theta=cfg.rope_theta, positions=positions, cache=cache,
         attn_impl=cfg.attn_impl, block=cfg.attn_block, attn_mixed=cfg.attn_mixed,
-        new_counts=new_counts, prefill=prefill, layer=layer,
+        new_counts=new_counts, prefill=prefill, layer=layer, slot=slot,
     )
     x = pin(x + h)
     aux = jnp.zeros((), jnp.float32)
@@ -90,13 +90,13 @@ def mla_block_specs(cfg) -> dict:
 
 
 def mla_block(p, x, cfg, *, cache=None, positions=None, new_counts=None, prefill=False,
-              layer=()):
+              layer=(), slot=None):
     h, new_cache = attn.mla_attention(
         p["attn"], rmsnorm(p["ln1"], x),
         n_heads=cfg.n_heads, d_nope=cfg.mla_d_nope, d_rope=cfg.mla_d_rope, d_v=cfg.mla_d_v,
         rope_theta=cfg.rope_theta, positions=positions, cache=cache,
         attn_impl=cfg.attn_impl, block=cfg.attn_block, attn_mixed=cfg.attn_mixed,
-        new_counts=new_counts, prefill=prefill, layer=layer,
+        new_counts=new_counts, prefill=prefill, layer=layer, slot=slot,
     )
     x = x + h
     f = ffn_mod.swiglu(p["ffn"], rmsnorm(p["ln2"], x))

@@ -329,8 +329,34 @@ def _decode_stack(block, stacked, caches, x, active, lead=()):
     return jax.lax.scan(body, x, (stacked, caches))
 
 
+# the per-slot leaves of a DecodeState that are not cache payloads: the
+# caches' lengths and the positions, with the slots on their last axis
+_SLOT_LEAVES = ("length", "positions")
+
+
+def _is_slot_leaf(path) -> bool:
+    return getattr(path[-1], "name", getattr(path[-1], "key", "")) in _SLOT_LEAVES
+
+
+def _slot_rows(state: DecodeState, slot, rows: int) -> DecodeState:
+    """``state`` with lengths and positions cut to slots ``slot`` ..
+    ``slot + rows - 1``; the cache payloads stay whole."""
+    return jax.tree_util.tree_map_with_path(
+        lambda p, x: jax.lax.dynamic_slice_in_dim(x, slot, rows, axis=-1) if _is_slot_leaf(p) else x,
+        state)
+
+
+def _put_slot_rows(full: DecodeState, rows: DecodeState, slot) -> DecodeState:
+    """``rows`` (from :func:`_slot_rows`) with its lengths and positions put
+    back into those of ``full`` at ``slot``."""
+    return jax.tree_util.tree_map_with_path(
+        lambda p, f, r: jax.lax.dynamic_update_slice_in_dim(f, r, slot, axis=-1)
+        if _is_slot_leaf(p) else r,
+        full, rows)
+
+
 def decode_step(params, state: DecodeState, batch, cfg, *, new_counts=None,
-                prefill: bool = False):
+                prefill: bool = False, slot=None):
     """One serve step: embed the new token(s), run all blocks against the
     caches, return (logits, new DecodeState).  ``batch['tokens']`` (B, S)
     (or ``batch['embeds']`` (B, S, m) for the audio family); S == 1 is the
@@ -350,22 +376,33 @@ def decode_step(params, state: DecodeState, batch, cfg, *, new_counts=None,
         at position 0 (admission-time batched prefill); under an ``sp_ring``
         recipe the attention families run the chunk through the
         sequence-parallel ring plan.  A prefill step returns (B, 1, vocab)
-        logits: those of each row's last valid token.
+        logits: those of each row's last valid token;
+      * ``slot`` (a traced int32 scalar) makes the batch's R rows cache slots
+        ``slot`` .. ``slot + R - 1`` of a state with more slots: only those
+        slots' cache rows, lengths and positions are read and written, and
+        the other slots cost nothing (the engine's one-row admission
+        prefill).  Length-indexed caches only: a recurrent state has no
+        per-slot write.
     Rows may leave garbage *beyond* their valid count inside the cache
     capacity — sound for non-windowed caches because the next write starts
     at ``length + count`` and the attention mask never reads past ``length``.
     The length-indexed caches are written in place: a step moves the
     positions it produces, not the cache.
     """
+    full = state
+    R, S = (batch["embeds"] if cfg.input_kind == "embeds" else batch["tokens"]).shape[:2]
+    if slot is not None:
+        if cfg.family in ("ssm", "hybrid"):
+            raise ValueError(f"{cfg.family!r} state cannot be stepped one slot at a time")
+        state = _slot_rows(state, slot, R)
     positions = state.positions
-    S = (batch["embeds"] if cfg.input_kind == "embeds" else batch["tokens"]).shape[1]
     pos2d = positions[:, None] + jnp.arange(S, dtype=positions.dtype)[None, :]
     active = None if new_counts is None else new_counts > 0
     adv = S if new_counts is None else new_counts
     x = embed_inputs(params, batch, cfg, positions=pos2d)
     fam = cfg.family
     caches = state.caches
-    kw = dict(positions=pos2d, new_counts=new_counts, prefill=prefill)
+    kw = dict(positions=pos2d, new_counts=new_counts, prefill=prefill, slot=slot)
 
     def attn_block(p, x, c, layer):
         return blk.attn_block(p, x, cfg, cache=c, layer=layer, **kw)
@@ -427,7 +464,8 @@ def decode_step(params, state: DecodeState, batch, cfg, *, new_counts=None,
         last = jnp.maximum(jnp.broadcast_to(adv, positions.shape) - 1, 0)
         x = x[jnp.arange(x.shape[0]), last][:, None]
     logits = lm_logits(params, x, cfg)
-    return logits, DecodeState(caches=new_caches, positions=positions + adv)
+    new = DecodeState(caches=new_caches, positions=positions + adv)
+    return logits, new if slot is None else _put_slot_rows(full, new, slot)
 
 
 # =============================================================== helpers ====

@@ -8,10 +8,12 @@ their slot and the next queued request is prefilled into it.
 Engine phases map onto the comm layer (see ``repro.core``'s "Serving on the
 comm layer" notes):
 
-  * **admission-time prefill** runs the whole prompt as one masked chunk
-    through ``lm.decode_step(prefill=True)``; under an ``sp_ring`` recipe
-    the chunk's attention is the sequence-parallel ring plan — the
-    ``Allgatherv``-over-seq-shards phase;
+  * **admission-time prefill** runs each admitted prompt as one chunk
+    through ``lm.decode_step(prefill=True)``, one program call per admitted
+    slot: a one-row program that reads and writes only that slot's rows of
+    the donated cache (the slot a traced index), so resident rows cost
+    nothing; under an ``sp_ring`` recipe the chunk's attention is the
+    sequence-parallel ring plan — the ``Allgatherv``-over-seq-shards phase;
   * **decode** runs the GSPMD path (single host / recipe);
   * given a ``(data, model)`` mesh and ``microbatches``, both run instead
     through the explicit tensor-parallel step of
@@ -32,8 +34,11 @@ iteration of :meth:`Engine.run`:
   * ``engine.admit`` (args ``admitted``, ``queued``, ``kv_valid_bytes``):
     a round that admits at least one request — queue pops, ledger, slot
     state reset;
-  * ``engine.prefill_launch`` (``rows``, ``bucket``): the prefill batch
-    built, copied to the device and its step program(s) dispatched;
+  * ``engine.prefill_launch`` (``rows``, ``bucket``, ``calls``,
+    ``padded_tokens``): the prefill feeds built, copied to the device and
+    the step program called ``calls`` times; ``padded_tokens`` is the token
+    rows those calls run beyond the ``rows`` prompts' own (padding to the
+    bucket, and idle slots where a call runs every slot);
   * ``engine.decode_launch`` (``rows``): the decode feed built, copied and
     the decode step dispatched;
   * ``engine.fetch``: the logits sliced and pulled to the host, which waits
@@ -167,33 +172,39 @@ class Engine:
         self._key = jax.random.PRNGKey(scfg.seed)
         self._featurize = featurizer or (lambda ids: _np_sinusoidal(ids, cfg.d_model))
         self._embeds_in = cfg.input_kind == "embeds"
-        self._chunk_prefill = cfg.family in _CHUNK_FAMILIES
+        tp = mesh is not None and microbatches
+        # one prefill program kind per engine: one row per call into its
+        # slot; all slots in one call (the TP program steps every slot); or
+        # token by token over all slots (recurrent state, capacity dispatch)
+        self._prefill_mode = ("token" if cfg.family not in _CHUNK_FAMILIES
+                              else "slots" if tp else "row")
 
-        def gspmd_step(params, state, batch, counts, *, prefill=False):
+        def gspmd_step(params, state, batch, counts, *, prefill=False, slot=None):
             # Steps run under pinned rounding: activation-dtype boundaries
             # materialize where the source says, so the scan-fused oracle jit
             # and the unrolled TP shard_map emit the same number stream (see
             # models/numerics.py).
             with use_recipe(self.recipe), pinned_rounding():
                 return lm.decode_step(params, state, batch, cfg,
-                                      new_counts=counts, prefill=prefill)
+                                      new_counts=counts, prefill=prefill, slot=slot)
 
         # the compiled step programs: (params, state, batch, counts) ->
         # (logits, state); the state is donated, so the KV cache is updated
-        # in place instead of copied every step
-        if mesh is not None and microbatches:
+        # in place instead of copied every step.  The single-host prefill
+        # takes a fifth argument, the int32 slot of the batch's first row.
+        if tp:
             from repro.serve.tp_decode import make_tp_decode_step, place_tp
 
-            tp = make_tp_decode_step(cfg, mesh, slots=B, microbatches=microbatches,
-                                     attn_impl=cfg.attn_impl)
+            step = make_tp_decode_step(cfg, mesh, slots=B, microbatches=microbatches,
+                                       attn_impl=cfg.attn_impl)
             # weights and cache live in the TP layout from here on, each
             # device holding its shard; the one TP program runs both the
             # prefill chunks and the decode steps, so neither reshards them
             self.params, self.state = place_tp(cfg, mesh, params, self.state)
-            self.prefill_fn = self.decode_fn = jax.jit(tp, donate_argnums=1)
+            self.prefill_fn = self.decode_fn = jax.jit(step, donate_argnums=1)
         else:
             self.prefill_fn = jax.jit(
-                lambda p, s, b, c: gspmd_step(p, s, b, c, prefill=True),
+                lambda p, s, b, c, slot=None: gspmd_step(p, s, b, c, prefill=True, slot=slot),
                 donate_argnums=1)
             self.decode_fn = jax.jit(gspmd_step, donate_argnums=1)
 
@@ -267,15 +278,32 @@ class Engine:
         self._prefill(newly)
 
     # ------------------------------------------------------------ prefill ----
-    def _prefill(self, newly) -> None:
-        """Admission-time batched prefill of all newly filled slots.
+    def _feed(self, feeds, rows: int, S: int):
+        """Host arrays of one step call: ``feeds`` [(row, ids or embeds)]
+        laid into a (rows, S) token batch (or (rows, S, d_model) embeds), and
+        each row's count of them."""
+        counts = np.zeros((rows,), np.int32)
+        if self._embeds_in:
+            buf = np.zeros((rows, S, self.cfg.d_model), np.float32)
+        else:
+            buf = np.zeros((rows, S), np.int32)
+        for r, feed in feeds:
+            buf[r, : len(feed)] = feed
+            counts[r] = len(feed)
+        return {"embeds" if self._embeds_in else "tokens": buf}, counts
 
-        Every prefill step carries per-slot ``new_counts`` so *only* the
-        target slots write their cache rows — resident requests' K/V is
-        untouched (the cross-slot clobbering fix: the old path wrote every
-        slot's row at the prefill position).  Chunk-capable families run the
-        whole prompt as one ``prefill=True`` chunk (the sp_ring batched
-        prefill path); recurrent/moe families step token-by-token under the
+    def _prefill(self, newly) -> None:
+        """Admission-time prefill of the newly filled slots, each prompt
+        less its last token (the first decode step feeds that one).
+
+        Chunk-capable families run each prompt as one ``prefill=True``
+        chunk padded to a power-of-two bucket.  The single-host engine
+        issues one one-row program call per admitted slot, back to back:
+        each writes that slot's cache rows, length and position alone, and
+        the resident slots are neither read nor computed.  The TP program
+        steps every slot, so there one call carries all the prompts and
+        per-slot ``new_counts`` keep the resident rows' K/V untouched.
+        Recurrent/moe families step token by token over all slots under the
         same masking."""
         with TraceAnnotation("engine.prefill_launch") as span:
             B = self.scfg.batch_slots
@@ -286,67 +314,46 @@ class Engine:
                     feeds.append((i, feed))
             if not feeds:
                 return
+            fed = sum(len(f) for _, f in feeds)
             S = max(len(f) for _, f in feeds)
-            if self._chunk_prefill:
-                S = min(self.scfg.max_len, 1 << (S - 1).bit_length())  # bucket: fewer recompiles
-                span.set_metadata(rows=len(feeds), bucket=S)
-                counts = np.zeros((B,), np.int32)
-                if self._embeds_in:
-                    buf = np.zeros((B, S, self.cfg.d_model), np.float32)
+            if self._prefill_mode == "token":
+                calls = [self._feed([(i, feed[t:t + 1]) for i, feed in feeds if t < len(feed)], B, 1)
+                         for t in range(S)]
+                bucket, rows = 1, B
+            else:
+                bucket = S = min(self.scfg.max_len, 1 << (S - 1).bit_length())  # fewer recompiles
+                if self._prefill_mode == "slots":
+                    calls, rows = [self._feed(feeds, B, S)], B
                 else:
-                    buf = np.zeros((B, S), np.int32)
-                for i, feed in feeds:
-                    buf[i, : len(feed)] = feed
-                    counts[i] = len(feed)
-                batch = ({"embeds": jnp.asarray(buf)} if self._embeds_in
-                         else {"tokens": jnp.asarray(buf)})
-                _, self.state = self.prefill_fn(self.params, self.state, batch,
-                                                jnp.asarray(counts))
-                for i, feed in feeds:
-                    self.ledger.advance(i, len(feed))
-                return
-            span.set_metadata(rows=len(feeds), bucket=1)
-            for t in range(S):
-                counts = np.zeros((B,), np.int32)
-                if self._embeds_in:
-                    buf = np.zeros((B, 1, self.cfg.d_model), np.float32)
-                else:
-                    buf = np.zeros((B, 1), np.int32)
-                for i, feed in feeds:
-                    if t < len(feed):
-                        buf[i, 0] = feed[t]
-                        counts[i] = 1
-                        self.ledger.advance(i, 1)
-                batch = ({"embeds": jnp.asarray(buf)} if self._embeds_in
-                         else {"tokens": jnp.asarray(buf)})
-                _, self.state = self.prefill_fn(self.params, self.state, batch,
-                                                jnp.asarray(counts))
+                    calls = [self._feed([(0, feed)], 1, S) + (np.int32(i),) for i, feed in feeds]
+                    rows = 1
+            span.set_metadata(rows=len(feeds), bucket=bucket, calls=len(calls),
+                              padded_tokens=len(calls) * rows * bucket - fed)
+            # every call's inputs go to the device before the first call,
+            # so the calls follow each other with nothing between them
+            for args in jax.device_put(calls):
+                _, self.state = self.prefill_fn(self.params, self.state, *args)
+            for i, feed in feeds:
+                self.ledger.advance(i, len(feed))
 
     # ------------------------------------------------------------- decode ----
     def _decode_once(self) -> None:
         B = self.scfg.batch_slots
         with TraceAnnotation("engine.decode_launch") as span:
-            counts = np.zeros((B,), np.int32)
-            if self._embeds_in:
-                buf = np.zeros((B, 1, self.cfg.d_model), np.float32)
-            else:
-                buf = np.zeros((B, 1), np.int32)
-            rows = 0
+            feeds = []
             for i, slot in enumerate(self.slots):
                 if slot.request_id is None:
                     continue
-                rows += 1
-                counts[i] = 1
-                if self._embeds_in:
-                    buf[i, 0] = (slot.next_embed if slot.next_embed is not None
-                                 else self._featurize([slot.tokens[-1]])[0])
+                if not self._embeds_in:
+                    feeds.append((i, [slot.tokens[-1]]))
+                elif slot.next_embed is not None:
+                    feeds.append((i, [slot.next_embed]))
                 else:
-                    buf[i, 0] = slot.tokens[-1]
+                    feeds.append((i, self._featurize([slot.tokens[-1]])))
+            rows = len(feeds)
             span.set_metadata(rows=rows)
-            batch = ({"embeds": jnp.asarray(buf)} if self._embeds_in
-                     else {"tokens": jnp.asarray(buf)})
-            logits, self.state = self.decode_fn(self.params, self.state, batch,
-                                                jnp.asarray(counts))
+            batch, counts = jax.device_put(self._feed(feeds, B, 1))
+            logits, self.state = self.decode_fn(self.params, self.state, batch, counts)
         with TraceAnnotation("engine.fetch"):
             logits = np.asarray(logits[:, -1, : self.cfg.vocab])  # strip padded vocab
         with TraceAnnotation("engine.sample", rows=rows):

@@ -196,3 +196,61 @@ def test_inactive_rows_keep_their_cache(arch, program):
                                   np.compress(keep, row_new, axis=-2)), (path, b)
             assert not np.array_equal(row_old, row_new), (path, b)
     np.testing.assert_array_equal(np.asarray(new.positions), lengths + counts)
+
+
+@pytest.mark.parametrize("arch", ["phi4-mini-3.8b", "minicpm3-4b", "musicgen-large"])
+def test_one_row_prefill_touches_only_its_slot(arch):
+    """An admission into slot 3 of a full 8-slot engine runs one one-row
+    prefill call that leaves every other slot's cache rows, length and
+    position bitwise as they were, and writes slot 3 exactly what a
+    1-slot engine writes for the same prompt.  Positions past the prompt's
+    bucket keep the slot's old bytes (only ``length`` is reset on
+    admission)."""
+    B, T, slot = 8, 64, 3
+    cfg = configs.get(arch, smoke=True)
+    params = lm.init_model(cfg, jax.random.PRNGKey(0))
+    eng, cfg = _engine(arch, params, B, T)
+    lengths = np.array([30, 5, 12, 0, 40, 7, 22, 9], np.int32)
+    key = jax.random.PRNGKey(2)
+
+    def fill(path, leaf):
+        name = getattr(path[-1], "name", "")
+        if name == "length":
+            return jnp.broadcast_to(jnp.asarray(lengths), leaf.shape)
+        return jax.random.normal(jax.random.fold_in(key, leaf.size), leaf.shape).astype(leaf.dtype)
+
+    eng.state = lm.DecodeState(caches=jax.tree_util.tree_map_with_path(fill, eng.state.caches),
+                               positions=jnp.asarray(lengths))
+    before = jax.tree.map(np.asarray, eng.state)
+    for j in range(B):
+        if j != slot:
+            eng.slots[j].request_id = 100 + j
+    shapes = []
+    row_fn = eng.prefill_fn
+    eng.prefill_fn = lambda *a: shapes.append(jax.tree.map(jnp.shape, a[2:])) or row_fn(*a)
+    prompt = list(range(3, 24))  # feeds 20 tokens, padded to the 32 bucket
+    eng.submit(0, prompt, max_new_tokens=2)
+    eng._fill_slots()
+    fed, bucket = len(prompt) - 1, 32
+    rows = {"tokens": (1, bucket)} if cfg.input_kind != "embeds" else {"embeds": (1, bucket, cfg.d_model)}
+    assert shapes == [(rows, (1,), ())]
+
+    one, _ = _engine(arch, params, 1, T)
+    one.submit(0, prompt, max_new_tokens=2)
+    one._fill_slots()
+    after = jax.tree.map(np.asarray, eng.state)
+    solo = jax.tree.map(np.asarray, one.state)
+    np.testing.assert_array_equal(after.positions, np.where(np.arange(B) == slot, fed, lengths))
+    for path, old in _cache_leaves(before.caches):
+        new = dict(_cache_leaves(after.caches))[path]
+        ref = dict(_cache_leaves(solo.caches))[path]
+        axis = old.ndim - (4 if path.endswith((".k", ".v")) else 3)
+        for b in range(B):
+            if b != slot:
+                assert np.array_equal(np.take(old, b, axis), np.take(new, b, axis)), (path, b)
+        row, ref_row = np.take(new, slot, axis), np.take(ref, 0, axis)
+        assert np.array_equal(row[..., :bucket, :], ref_row[..., :bucket, :]), path
+        assert np.array_equal(row[..., bucket:, :], np.take(old, slot, axis)[..., bucket:, :]), path
+    for path, leaf in jax.tree_util.tree_flatten_with_path(after.caches)[0]:
+        if getattr(path[-1], "name", "") == "length":
+            assert (leaf == np.where(np.arange(B) == slot, fed, lengths)).all(), path

@@ -1,5 +1,6 @@
 """Serving engine: generation, slot reuse (continuous batching), determinism."""
 import numpy as np
+import pytest
 
 import sys, os
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
@@ -200,3 +201,50 @@ print('OK')
 """
     )
     assert "OK" in out
+
+
+def test_admissions_reuse_the_warm_prefill_programs():
+    """Warm-up admits full 8-row batches in each bucket; later admissions of
+    1, 3 and 8 rows build no new prefill (or decode) executable, and an
+    admission of k rows issues k one-row prefill calls."""
+    eng, _ = _engine(slots=8, max_len=64)
+    prefill, decode = eng.prefill_fn, eng.decode_fn
+    calls = []
+    eng.prefill_fn = lambda *a: calls.append(a[2]["tokens"].shape) or prefill(*a)
+    buckets = (8, 16, 32)
+    rid = 0
+    for b in buckets:  # a prompt of b + 1 tokens feeds b
+        for _ in range(8):
+            eng.submit(rid, [3 + rid % 50] * (b + 1), max_new_tokens=2)
+            rid += 1
+        eng.run()
+    assert prefill._cache_size() == len(buckets)
+    warm = decode._cache_size()
+    for k, b in ((1, 8), (3, 16), (8, 32)):
+        calls.clear()
+        for r in range(k):  # every prompt of the admission in bucket b
+            eng.submit(rid, list(range(2, 2 + b + 1 - r)), max_new_tokens=2)
+            rid += 1
+        eng.run(max_steps=1)
+        assert calls == [(1, b)] * k
+        eng.run()
+    assert prefill._cache_size() == len(buckets)
+    assert decode._cache_size() == warm
+
+
+@pytest.mark.parametrize("arch", ["rwkv6-3b", "zamba2-7b", "phi3.5-moe-42b-a6.6b"])
+def test_per_token_families_prefill_all_slots_per_token(arch):
+    """Recurrent (ssm, hybrid) and capacity-dispatch (moe) families keep the
+    per-token prefill: one all-slot (8, 1) program call per prompt token,
+    with no slot index."""
+    cfg = configs.get(arch, smoke=True)
+    params = lm.init_model(cfg, jax.random.PRNGKey(0))
+    eng = Engine(cfg, params, ServeConfig(max_len=32, batch_slots=8, eos_token=-1))
+    prefill = eng.prefill_fn
+    calls = []
+    eng.prefill_fn = lambda *a: calls.append((len(a), a[2]["tokens"].shape)) or prefill(*a)
+    eng.submit(0, [5, 9, 13, 2, 7], max_new_tokens=2)  # feeds 4
+    eng.submit(1, [3, 3, 4], max_new_tokens=2)  # feeds 2
+    done = eng.run()
+    assert sorted(done) == [0, 1]
+    assert calls == [(4, (8, 1))] * 4

@@ -93,11 +93,14 @@ def test_one_span_per_phase_and_step(served):
     assert calls["decode"] == DECODE_STEPS
     for name in ("engine.decode_launch", "engine.fetch", "engine.sample"):
         assert len(named(served, name)) == DECODE_STEPS, name
-    # two admitting rounds, each followed by one prefill program
+    # two admitting rounds, each followed by one prefill launch that calls
+    # the one-row prefill program once per admitted request
     admits = named(served, "engine.admit")
     assert [s[3]["admitted"] for s in admits] == [2, 1]
     assert [s[3]["queued"] for s in admits] == [1, 0]
-    assert len(named(served, "engine.prefill_launch")) == calls["prefill"] == 2
+    launches = named(served, "engine.prefill_launch")
+    assert len(launches) == 2
+    assert sum(s[3]["calls"] for s in launches) == calls["prefill"] == 3
 
 
 def test_queued_span_per_request(served):
@@ -118,6 +121,8 @@ def test_span_args(served):
     prefills = named(served, "engine.prefill_launch")
     # rows fed (prompt less its last token) padded to a power of two
     assert [(s[3]["rows"], s[3]["bucket"]) for s in prefills] == [(2, 8), (1, 4)]
+    # one call per row; padding: 8 - 4 and 8 - 8 tokens, then 4 - 3
+    assert [(s[3]["calls"], s[3]["padded_tokens"]) for s in prefills] == [(2, 4), (1, 1)]
     assert [s[3]["rows"] for s in named(served, "engine.decode_launch")] == [2] * DECODE_STEPS
     assert [s[3]["rows"] for s in named(served, "engine.sample")] == [2] * DECODE_STEPS
 

@@ -306,3 +306,53 @@ print('OK')
 """
     )
     assert "OK" in out
+
+
+def test_one_row_prefill_ring_matches_no_recipe(distributed):
+    """The engine's admission prefill under an sp_ring recipe: a one-row
+    chunk written into its slot (``decode_step(slot=...)``) runs the ring
+    plan on that row, and its logits, lengths, positions and cache match the
+    same call with no recipe.  Positions past the row's count hold padding,
+    which the ring attends over and the masked kernel does not, so only the
+    valid positions are compared."""
+    out = distributed(
+        """
+import dataclasses
+import numpy as np, jax, jax.numpy as jnp
+from repro import configs
+from repro.core import make_mesh
+from repro.models import lm
+from repro.models.sharding import make_recipe, use_recipe
+
+cfg = dataclasses.replace(configs.get('phi4-mini-3.8b', smoke=True),
+                          act_dtype=jnp.float32, param_dtype=jnp.float32)
+params = lm.init_model(cfg, jax.random.PRNGKey(0))
+B, T, S, slot = 4, 64, 32, 2
+state = lm.DecodeState(caches=lm.init_cache(cfg, B, T), positions=jnp.zeros((B,), jnp.int32))
+batch = {'tokens': jax.random.randint(jax.random.PRNGKey(1), (1, S), 0, cfg.vocab)}
+counts = jnp.asarray([27], jnp.int32)
+
+def step(recipe):
+    def f(st, slot):
+        with use_recipe(recipe):
+            return lm.decode_step(params, st, batch, cfg, new_counts=counts,
+                                  prefill=True, slot=slot)
+    return jax.jit(f)
+
+recipe = make_recipe(cfg, make_mesh((2, 4), ('data', 'model')), attn_mode='sp_ring')
+ring_step = step(recipe)
+assert 'collective_permute' in ring_step.lower(state, jnp.int32(slot)).as_text()
+ref_logits, ref = step(None)(state, jnp.int32(slot))
+logits, got = ring_step(state, jnp.int32(slot))
+assert np.abs(np.asarray(logits) - np.asarray(ref_logits)).max() < 1e-4
+assert np.array_equal(np.asarray(got.positions), [0, 0, 27, 0])
+assert np.array_equal(np.asarray(got.caches.length), np.asarray(ref.caches.length))
+assert (np.asarray(got.caches.length)[:, slot] == 27).all()
+for a, b in ((got.caches.k, ref.caches.k), (got.caches.v, ref.caches.v)):
+    a, b = np.asarray(a), np.asarray(b)
+    assert np.abs(a - b)[..., :27, :].max() < 1e-5
+    assert not np.delete(a, slot, axis=1).any()
+print('OK')
+"""
+    )
+    assert "OK" in out

@@ -224,7 +224,8 @@ def test_engine_steps_update_the_cache_in_place_for_v5e(one_chip, no_cache, arch
     """The engine's step programs at full widths (depth cut to 2 layers; 8
     slots x 2048 positions) write the stacked cache in place: no copy of a
     stacked cache leaf, and the leaves keep the layout the donated buffers
-    arrive in.  A layer scan that passes the cache through its inputs and
+    arrive in.  The decode step feeds all 8 slots; the prefill chunk one
+    row, into the slot its traced index names.  A layer scan that passes the cache through its inputs and
     outputs copies it whole; a carried stack laid out to suit the masked
     rows' read is converted in and out.  MLA's 32-wide rope-key cache is
     left out: the compiler lays it out positions-minor on the device and
@@ -239,9 +240,12 @@ def test_engine_steps_update_the_cache_in_place_for_v5e(one_chip, no_cache, arch
     params = on(jax.eval_shape(lambda: lm.init_model(cfg, jax.random.PRNGKey(0))))
     eng = Engine(cfg, params, ServeConfig(max_len=T, batch_slots=slots))
     state = on(jax.eval_shape(lambda: eng.state))
-    fn = eng.decode_fn if S == 1 else eng.prefill_fn
-    txt = fn.lower(params, state, {"tokens": _arg(one_chip, (slots, S), jnp.int32)},
-                   _arg(one_chip, (slots,), jnp.int32)).compile().as_text()
+    if S == 1:
+        fn, rows, slot = eng.decode_fn, slots, ()
+    else:
+        fn, rows, slot = eng.prefill_fn, 1, (_arg(one_chip, (), jnp.int32),)
+    txt = fn.lower(params, state, {"tokens": _arg(one_chip, (rows, S), jnp.int32)},
+                   _arg(one_chip, (rows,), jnp.int32), *slot).compile().as_text()
     leaves = [state.caches.k, state.caches.v] if arch.startswith("phi4") else [state.caches.c]
     for leaf in leaves:
         shape = re.escape(",".join(map(str, leaf.shape)))

@@ -157,8 +157,8 @@ def comm_volume_model(algo: str, *, ni: int, nj: int, nk: int,
     while the 2-D SUMMA ring moves only the (nk/Cc, nj/R) panel per step,
     O(n^2/sqrt(P)) on a square grid.  ``ring_bytes`` is exact and matches the
     ``collective-permute`` bytes the HLO walker counts in the dry-run trace;
-    the reduce-scatter/broadcast terms follow the conventions of
-    ``repro.launch.roofline`` (result bytes x1).
+    the reduce-scatter/broadcast terms follow the HLO walker's conventions
+    (``repro.launch.hlo_walk``: result bytes x1).
     """
     if algo == "summa2d":
         if grid is None:

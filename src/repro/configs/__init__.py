@@ -1,7 +1,7 @@
 """Architecture config registry: ``repro.configs.get("qwen2.5-32b")``."""
 from importlib import import_module
 
-from .base import ArchConfig, ShapeCell, SHAPES
+from .base import ArchConfig, ShapeCell
 
 _MODULES = {
     "phi4-mini-3.8b": "phi4_mini_3_8b",
@@ -26,4 +26,4 @@ def get(name: str, *, smoke: bool = False) -> ArchConfig:
     return mod.SMOKE if smoke else mod.CONFIG
 
 
-__all__ = ["ArchConfig", "ShapeCell", "SHAPES", "ARCH_IDS", "get"]
+__all__ = ["ArchConfig", "ShapeCell", "ARCH_IDS", "get"]

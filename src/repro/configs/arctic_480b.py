@@ -7,7 +7,7 @@ CONFIG = ArchConfig(
     n_layers=35, d_model=7168, n_heads=56, n_kv=8, d_ff=4864,
     vocab=32000, head_dim=128,
     ffn_kind="moe", n_experts=128, moe_top_k=2, moe_dense_residual=True,
-    moe_groups=16,  # grouped dispatch over the data axis (§Perf: confirmed win)
+    moe_groups=16,  # grouped dispatch over the data axis
 )
 
 SMOKE = ArchConfig(

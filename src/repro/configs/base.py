@@ -1,4 +1,4 @@
-"""Architecture configuration schema + the shape cells assigned to every arch.
+"""Architecture configuration schema and the shape cell of a run.
 
 Each assigned architecture gets one ``src/repro/configs/<id>.py`` exporting
 ``CONFIG`` (the exact published configuration) and ``SMOKE`` (a reduced
@@ -12,7 +12,7 @@ from typing import Any
 
 import jax.numpy as jnp
 
-__all__ = ["ArchConfig", "ShapeCell", "SHAPES", "round_up"]
+__all__ = ["ArchConfig", "ShapeCell", "round_up"]
 
 
 def round_up(x: int, mult: int) -> int:
@@ -25,15 +25,6 @@ class ShapeCell:
     seq_len: int
     global_batch: int
     kind: str  # 'train' | 'prefill' | 'decode'
-
-
-# The four assigned input-shape cells for the LM families.
-SHAPES: dict[str, ShapeCell] = {
-    "train_4k": ShapeCell("train_4k", 4096, 256, "train"),
-    "prefill_32k": ShapeCell("prefill_32k", 32768, 32, "prefill"),
-    "decode_32k": ShapeCell("decode_32k", 32768, 128, "decode"),
-    "long_500k": ShapeCell("long_500k", 524288, 1, "decode"),
-}
 
 
 @dataclasses.dataclass(frozen=True)

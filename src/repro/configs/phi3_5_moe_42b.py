@@ -7,7 +7,7 @@ CONFIG = ArchConfig(
     n_layers=32, d_model=4096, n_heads=32, n_kv=8, d_ff=6400,
     vocab=32064, head_dim=128,
     ffn_kind="moe", n_experts=16, moe_top_k=2,
-    moe_groups=16,  # grouped dispatch over the data axis (§Perf: confirmed win)
+    moe_groups=16,  # grouped dispatch over the data axis
     # expert-parallel ragged a2a dispatch when the recipe has a model axis;
     # grouped dispatch above stays the fallback for ineligible meshes
     moe_dispatch="ep",

@@ -14,8 +14,8 @@ positions ``>= min(cache_len, T)`` are invalid (ring-buffer aware), and query
 ``t`` iff ``t <= q_start[b] + j`` — the continuous-batching per-row mask.
 Both per-row scalars ride in through TPU scalar prefetch (SMEM), so no block
 of the kernel is a sub-tile slice of a per-row vector.  The probabilities
-round to the cache dtype before the p@v contraction, mirroring the jnp
-path's pinned-rounding boundary.
+round to the cache dtype before the p@v contraction, as on the jnp
+path.
 
 GQA is absorbed in the grid: one program per (batch, kv-group) reads each
 K/V tile once for all ``rep = Hq // G`` query heads of the group.  A
@@ -81,7 +81,7 @@ def _decode_kernel(len_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
                                         l_ref.shape[1:])
             m_ref[h] = jnp.broadcast_to(m_new[:, None], m_ref.shape[1:])
             # probabilities round to the cache dtype before the contraction,
-            # like the jnp decode path (there: normalized + pinned; here the
+            # like the jnp decode path (there: normalized first; here the
             # normalizer is applied once after the last KV block)
             acc_ref[h] = acc_ref[h] * alpha[:, None] + jnp.dot(
                 p.astype(v.dtype), v, preferred_element_type=jnp.float32)

@@ -182,8 +182,8 @@ def decode_attention_ref(q, k_cache, v_cache, cache_len, *, q_start=None,
     masking and the per-row chunk-causality mask (query ``j`` of row ``b``
     sits at ``q_start[b] + j``).  (The model-facing jnp
     path in ``models.attention.attention_decode`` additionally rounds the
-    normalized probabilities to the cache dtype under a pinned barrier; this
-    oracle keeps everything f32.)"""
+    normalized probabilities to the cache dtype; this oracle keeps
+    everything f32.)"""
     B, Hq, S, D = q.shape
     _, G, T, _ = k_cache.shape
     rep = Hq // G

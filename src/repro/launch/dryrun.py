@@ -1,5 +1,5 @@
 import os
-os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=512 "
+os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
                            "--xla_disable_hlo_passes=cpu-all-reduce-combiner")
 
 # NOTE: the lines above MUST run before any other import (jax locks the
@@ -12,182 +12,41 @@ os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=512 "
 # (tests/test_tpu_compile.py::test_tp_decode_reductions_stay_apart_for_v5e),
 # and with the CPU pass off the gate sees the same collectives.
 
-DOC = """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
-production mesh and record memory/cost/collective evidence.
-
-For each cell:
-  * train_4k     -> ``train_step`` (fwd+bwd+AdamW, microbatched)
-  * prefill_32k  -> ``prefill_step`` (forward to logits)
-  * decode/long  -> ``serve_step`` (one token against the full KV cache)
+DOC = """Program gates: lower + compile each comm-plan program on fake CPU
+devices and prove its collective schedule from the optimized HLO.
 
 Everything is lowered from ShapeDtypeStructs — no arrays are allocated.
-``compiled.memory_analysis()`` proves the per-device footprint fits HBM;
-``compiled.cost_analysis()`` + the optimized HLO feed §Roofline.
+Every gate runs on a grid of at most 8 devices.
 
 Usage:
-  python -m repro.launch.dryrun --arch qwen2.5-32b --shape train_4k
-  python -m repro.launch.dryrun --all [--multi-pod] [--out benchmarks/results]
-  python -m repro.launch.dryrun --arch ... --shape ... --attn-mode sp \
-         --set moe_capacity_factor=1.0 --microbatches 4
   python -m repro.launch.dryrun --summa-gemm   # SUMMA ring: 0 serialized gate
+  python -m repro.launch.dryrun --uneven       # ragged SUMMA: same gate + byte model
   python -m repro.launch.dryrun --sp-ring      # ring attention: same gate
   python -m repro.launch.dryrun --serve        # serving TP decode: same gate
+  python -m repro.launch.dryrun --moe          # MoE expert-parallel dispatch
   python -m repro.launch.dryrun --train        # ZeRO train step: 0 serialized
                                                # reduce-scatter/all-gather gate
 
-The program gates (--summa-gemm / --uneven / --sp-ring / --serve) also
-assert *plan/HLO agreement*: each program's declared comm-plan intent
-(repro.core.plan) must match what the HLO walker proves about the compiled
-artifact.  ``--plan-report out.json`` runs all of them and writes the
-per-plan agreement table (the nightly CI artifact).
+The program gates also assert *plan/HLO agreement*: each program's declared
+comm-plan intent (repro.core.plan) must match what the HLO walker proves
+about the compiled artifact.  ``--plan-report out.json`` runs all of them
+and writes the per-plan agreement table (the nightly CI artifact).
 """
 
 import argparse
-import dataclasses
 import json
-import time
-import traceback
 
 import jax
 import numpy as np
 
 from repro import configs
-from repro.configs import SHAPES
 from repro.data.pipeline import batch_specs
-from repro.launch.mesh import make_production_mesh
-from repro.launch import roofline as rl
 from repro.models import lm
-from repro.models.sharding import (
-    make_recipe,
-    use_recipe,
-    batch_shardings,
-    decode_state_shardings,
-)
-from repro.train.optimizer import OptConfig, init_opt_state
-from repro.train.trainer import make_train_step, make_serve_step
+from repro.train.optimizer import OptConfig
 
 
 def _abstract(tree):
     return jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree)
-
-
-def _apply_overrides(cfg, sets: list[str]):
-    if not sets:
-        return cfg
-    kw = {}
-    for s in sets:
-        k, v = s.split("=", 1)
-        if k.endswith("dtype"):
-            kw[k] = np.dtype(v)  # 'bfloat16' works via ml_dtypes
-            continue
-        field_type = type(getattr(cfg, k))
-        if field_type is bool or v.lower() in ("true", "false"):
-            kw[k] = v.lower() in ("1", "true")
-        elif getattr(cfg, k) is None:
-            kw[k] = v
-        else:
-            kw[k] = field_type(v)
-    return dataclasses.replace(cfg, **kw)
-
-
-def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False, attn_mode: str = "auto",
-               microbatches: int = 1, sets: list[str] | None = None, recipe_overrides=None,
-               act_overrides=None, verbose: bool = True):
-    """Lower+compile one cell; returns (record dict, compiled)."""
-    cfg = _apply_overrides(configs.get(arch), sets or [])
-    shape = SHAPES[shape_name]
-    mesh = make_production_mesh(multi_pod=multi_pod)
-    chips = int(np.prod([mesh.shape[a] for a in mesh.axis_names]))
-    recipe = make_recipe(cfg, mesh, attn_mode=attn_mode,
-                         overrides=recipe_overrides, act_overrides=act_overrides)
-
-    specs = lm.build_specs(cfg)
-    params_abs = lm.abstract_model(cfg)
-    params_sh = recipe.param_shardings(specs)
-    batch_abs = batch_specs(cfg, shape)
-    batch_sh = batch_shardings(recipe, batch_abs)
-    t0 = time.time()
-
-    if shape.kind == "train":
-        ocfg = OptConfig()
-        opt_abs = jax.eval_shape(lambda p: init_opt_state(p, ocfg), params_abs)
-        # opt moments shard exactly like params; scalar step replicates
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        rep = NamedSharding(mesh, P())
-        opt_sh = type(opt_abs)(
-            step=rep,
-            mu=params_sh,
-            nu=params_sh,
-            err=(),
-        )
-        step_fn = make_train_step(cfg, recipe, ocfg, microbatches=microbatches)
-        jitted = jax.jit(step_fn, in_shardings=(params_sh, opt_sh, batch_sh))
-        with mesh:
-            lowered = jitted.lower(params_abs, opt_abs, batch_abs)
-    elif shape.kind == "prefill":
-        def prefill_step(params, batch):
-            with use_recipe(recipe):
-                logits, _ = lm.forward(params, batch, cfg)
-            return logits
-
-        jitted = jax.jit(prefill_step, in_shardings=(params_sh, batch_sh))
-        with mesh:
-            lowered = jitted.lower(params_abs, batch_abs)
-    else:  # decode
-        cache_len = shape.seq_len
-        B = shape.global_batch
-        state_abs = jax.eval_shape(
-            lambda: lm.DecodeState(
-                caches=lm.init_cache(cfg, B, cache_len),
-                positions=jax.numpy.zeros((B,), jax.numpy.int32),
-            )
-        )
-        state_sh = decode_state_shardings(recipe, state_abs)
-        serve_fn = make_serve_step(cfg, recipe)
-        jitted = jax.jit(serve_fn, in_shardings=(params_sh, state_sh, batch_sh))
-        with mesh:
-            lowered = jitted.lower(params_abs, state_abs, batch_abs)
-
-    with mesh:
-        compiled = lowered.compile()
-    compile_s = time.time() - t0
-
-    mem = compiled.memory_analysis()
-    cost = compiled.cost_analysis() or {}
-    hlo = compiled.as_text()
-    model_flops = _model_flops(cfg, shape)
-    rep = rl.roofline_report(
-        arch=arch, shape=shape_name,
-        mesh_name="2x16x16" if multi_pod else "16x16",
-        chips=chips, cost=cost, hlo_text=hlo, model_flops=model_flops,
-    )
-    record = {
-        "arch": arch,
-        "shape": shape_name,
-        "mesh": rep.mesh,
-        "chips": chips,
-        "attn_mode": recipe.attn_mode,
-        "sp_ring": recipe.sp_ring,
-        "compile_seconds": round(compile_s, 1),
-        "memory": _mem_dict(mem),
-        "cost": {k: cost.get(k) for k in ("flops", "bytes accessed") if k in cost},
-        "roofline": rep.to_json(),
-        "hlo_bytes": len(hlo),
-    }
-    if verbose:
-        print(json.dumps({k: v for k, v in record.items() if k != "roofline"}, indent=None))
-        print("  roofline:", json.dumps({
-            k: record["roofline"][k]
-            for k in ("t_compute", "t_memory", "t_collective", "dominant", "useful_ratio", "roofline_fraction")
-        }))
-        print("  overlap:", json.dumps({
-            k: record["roofline"][k]
-            for k in ("collectives_overlapped", "collectives_serialized",
-                      "collective_overlap_fraction", "coll_exposed_bytes",
-                      "t_collective_exposed")
-        }))
-    return record, compiled
 
 
 def _import_examples_gemm():
@@ -671,33 +530,6 @@ def train_dryrun(*, arch: str = "phi4-mini-3.8b", ranks: int = 8,
     return out
 
 
-def _mem_dict(mem):
-    if mem is None:
-        return {}
-    out = {}
-    for attr in ("argument_size_in_bytes", "output_size_in_bytes",
-                 "temp_size_in_bytes", "generated_code_size_in_bytes"):
-        try:
-            out[attr] = int(getattr(mem, attr))
-        except Exception:
-            pass
-    return out
-
-
-def _model_flops(cfg, shape) -> float:
-    """MODEL_FLOPS = 6*N*D (dense) / 6*N_active*D (MoE); decode D = batch
-    tokens; prefill D = tokens, factor 2 (no backward)."""
-    n = lm.count_params(cfg, active_only=True)
-    if shape.kind == "train":
-        tokens = shape.global_batch * shape.seq_len
-        return 6.0 * n * tokens
-    if shape.kind == "prefill":
-        tokens = shape.global_batch * shape.seq_len
-        return 2.0 * n * tokens
-    tokens = shape.global_batch  # one new token per sequence
-    return 2.0 * n * tokens
-
-
 def plan_report(path: str, verbose: bool = True) -> int:
     """Run every comm-plan dry run (SUMMA ring, ragged SUMMA ring, sp ring
     attention dense AND ragged seq) and write the per-plan overlap/agreement
@@ -782,27 +614,8 @@ def plan_report(path: str, verbose: bool = True) -> int:
     return 1 if disagreements else 0
 
 
-def iter_cells():
-    for arch in configs.ARCH_IDS:
-        cfg = configs.get(arch)
-        for shape_name in SHAPES:
-            if shape_name == "long_500k" and not cfg.sub_quadratic:
-                yield arch, shape_name, "skip"
-            else:
-                yield arch, shape_name, "run"
-
-
 def main() -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default=None)
-    ap.add_argument("--shape", default=None, choices=list(SHAPES))
-    ap.add_argument("--all", action="store_true")
-    ap.add_argument("--multi-pod", action="store_true")
-    ap.add_argument("--attn-mode", default="auto", choices=["auto", "tp", "sp", "sp_ring"])
-    ap.add_argument("--microbatches", type=int, default=1)
-    ap.add_argument("--set", action="append", default=[], help="cfg override k=v")
-    ap.add_argument("--out", default="benchmarks/results")
-    ap.add_argument("--tag", default="baseline")
     ap.add_argument("--summa-gemm", action="store_true",
                     help="dry-run the SUMMA ring program and report the "
                          "kind-generic collective overlap classification")
@@ -964,56 +777,8 @@ def main() -> None:
             bad += 0 if single["serialized_a2a"] > 0 else 1
         raise SystemExit(1 if bad else 0)
 
-    os.makedirs(args.out, exist_ok=True)
-    mesh_tag = "multipod" if args.multi_pod else "singlepod"
-
-    cells = []
-    if args.all:
-        cells = list(iter_cells())
-    else:
-        assert args.arch and args.shape, "--arch/--shape or --all"
-        cells = [(args.arch, args.shape, "run")]
-
-    results, failures = [], []
-    for arch, shape_name, status in cells:
-        key = f"{arch}__{shape_name}__{mesh_tag}__{args.tag}"
-        path = os.path.join(args.out, key + ".json")
-        if status == "skip":
-            rec = {"arch": arch, "shape": shape_name, "mesh": mesh_tag,
-                   "status": "skipped", "reason": "full attention is O(S^2); long_500k runs only for sub-quadratic archs (see DESIGN.md)"}
-            json.dump(rec, open(path, "w"), indent=1)
-            print(f"[skip] {key}")
-            continue
-        if os.path.exists(path) and args.all:
-            try:
-                prev = json.load(open(path))
-            except Exception:
-                prev = {}
-            if prev.get("status") == "ok":
-                print(f"[cached] {key}")
-                continue
-        print(f"[lower+compile] {key}", flush=True)
-        try:
-            rec, _ = lower_cell(
-                arch, shape_name, multi_pod=args.multi_pod,
-                attn_mode=args.attn_mode, microbatches=args.microbatches,
-                sets=args.set,
-            )
-            rec["status"] = "ok"
-            rec["tag"] = args.tag
-            json.dump(rec, open(path, "w"), indent=1)
-            results.append(rec)
-        except Exception as e:  # noqa: BLE001 - record and continue
-            failures.append((key, repr(e)))
-            json.dump({"arch": arch, "shape": shape_name, "mesh": mesh_tag,
-                       "status": "failed", "error": traceback.format_exc()},
-                      open(path, "w"), indent=1)
-            print(f"[FAILED] {key}: {e}")
-    print(f"\ndone: {len(results)} ok, {len(failures)} failed")
-    for k, e in failures:
-        print("  FAIL", k, e[:200])
-    if failures:
-        raise SystemExit(1)
+    ap.error("choose a gate: --summa-gemm, --uneven, --sp-ring, --serve, "
+             "--moe, --train or --plan-report")
 
 
 if __name__ == "__main__":

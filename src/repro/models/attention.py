@@ -16,8 +16,8 @@ ring step   ``flash_attention_carry_pallas`` jnp online-softmax merge
             KV block, ``(acc, m, l)`` carry  and interpret-mode oracle)
             threaded across ring steps
 decode      ``flash_decode_pallas``          jnp dense streaming attention
-(serving)   (KV-block grid, online softmax   with pinned probability
-            in VMEM, per-slot masks)         rounding (bitwise oracle)
+(serving)   (KV-block grid, online softmax   (probabilities rounded to the
+            in VMEM, per-slot masks)         cache dtype before p@v)
 ==========  ===============================  ================================
 
 Overrides: ``attn_impl=`` on the model-facing ops (and ``impl=`` on
@@ -27,7 +27,7 @@ the CPU correctness oracle for the kernels, used by the dry-run gates'
 ``--attn-impl interpret``), or ``"jnp"``/``"ref"`` (the pure-jnp forms).
 ``None`` resolves per backend as above.  Within each path the variants
 agree: ring carry-chains are bitwise-equal to single-shot flash at f32, and
-decode stays within pinned-rounding tolerance of the jnp oracle.
+the decode kernel stays within a stated tolerance of the jnp form.
 
 The ring and decode structure around the kernels:
   * ``seq`` under a sequence-parallel ``sp_ring`` recipe becomes
@@ -54,7 +54,6 @@ from repro.core.p2p import shard_ring_shift_start
 from repro.core.plan import intent_of, ring
 from repro.kernels import ops
 from .module import pspec
-from .numerics import pin
 from .sharding import _fit_spec, current_recipe, shard_act
 
 # ------------------------------------------------------------------ RoPE ----
@@ -337,21 +336,16 @@ def attention_decode(q, k_cache, v_cache, cache_len, *, q_start=None,
     ``impl`` dispatch (see the module docstring's table): ``"pallas"`` /
     ``"interpret"`` run the flash-decoding Pallas kernel
     (:func:`repro.kernels.flash_decode.flash_decode_pallas`, KV-block grid +
-    online softmax) with the output pinned at the activation-dtype
-    boundary; ``"jnp"``/``"ref"`` (the non-TPU default) keep the dense jnp
-    path below, whose pinned probability rounding is the serving oracle.
+    online softmax); ``"jnp"``/``"ref"`` (the non-TPU default) keep the
+    dense jnp path below.
     """
     B, Hq, S, D = q.shape
     _, G, T, _ = k_cache.shape
     rep = Hq // G
     impl = impl or ("pallas" if jax.default_backend() == "tpu" else "jnp")
     if impl not in ("jnp", "ref"):
-        o = ops.flash_decode(q, k_cache, v_cache, cache_len,
-                             q_start=q_start, impl=impl, bk=block)
-        # same pinned boundary as the jnp path's rounded probabilities: the
-        # kernel output rounds to the activation dtype behind a barrier so
-        # schedule variants cannot fold the convert differently
-        return pin(o)
+        return ops.flash_decode(q, k_cache, v_cache, cache_len,
+                                q_start=q_start, impl=impl, bk=block)
     # the cache streams stay in their storage dtype (bf16); scores and the
     # p@v contraction accumulate in f32 — reading the cache IS the decode
     # roofline term, so it is never widened in HBM
@@ -371,10 +365,8 @@ def attention_decode(q, k_cache, v_cache, cache_len, *, q_start=None,
     s = jnp.where(mask, s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     # the probabilities round to the cache dtype *before* the p@v
-    # contraction; under pinned rounding (serving decode) a barrier stops
-    # XLA from folding that round into the f32 dot, so every caller —
-    # single-host or distributed — contracts the identical rounded weights
-    p = pin(p.astype(v_cache.dtype))
+    # contraction
+    p = p.astype(v_cache.dtype)
     o = jnp.einsum("bgrqs,bgsd->bgrqd", p, v_cache,
                    preferred_element_type=jnp.float32)
     return o.reshape(B, Hq, S, D).astype(q.dtype)
@@ -416,18 +408,18 @@ def gqa_attention(p, x, *, n_heads: int, n_kv: int, head_dim: int, rope_theta: f
     ``model`` axis; ``sp_ring_double_buffer=False`` selects the blocking
     reference variant, bit-identical at f32)."""
     B, S, _ = x.shape
-    q = shard_act(pin(jnp.einsum("bsm,mhd->bhsd", x, p["wq"].astype(x.dtype))), "q")
-    k = shard_act(pin(jnp.einsum("bsm,mgd->bgsd", x, p["wk"].astype(x.dtype))), "kv")
-    v = shard_act(pin(jnp.einsum("bsm,mgd->bgsd", x, p["wv"].astype(x.dtype))), "kv")
+    q = shard_act(jnp.einsum("bsm,mhd->bhsd", x, p["wq"].astype(x.dtype)), "q")
+    k = shard_act(jnp.einsum("bsm,mgd->bgsd", x, p["wk"].astype(x.dtype)), "kv")
+    v = shard_act(jnp.einsum("bsm,mgd->bgsd", x, p["wv"].astype(x.dtype)), "kv")
     if "bq" in p:
-        q = pin(q + p["bq"].astype(x.dtype)[None, :, None, :])
-        k = pin(k + p["bk"].astype(x.dtype)[None, :, None, :])
-        v = pin(v + p["bv"].astype(x.dtype)[None, :, None, :])
+        q = q + p["bq"].astype(x.dtype)[None, :, None, :]
+        k = k + p["bk"].astype(x.dtype)[None, :, None, :]
+        v = v + p["bv"].astype(x.dtype)[None, :, None, :]
     if positions is None:
         positions = jnp.arange(S)
     cos, sin = rope_angles(positions, head_dim, rope_theta)
-    q = pin(apply_rope(q, cos, sin))
-    k = pin(apply_rope(k, cos, sin))
+    q = apply_rope(q, cos, sin)
+    k = apply_rope(k, cos, sin)
     recipe = current_recipe()
     if cache is not None:
         adv = S if new_counts is None else new_counts
@@ -458,9 +450,9 @@ def gqa_attention(p, x, *, n_heads: int, n_kv: int, head_dim: int, rope_theta: f
             return shard_act(out, "hidden")[:, :S], new_cache
         # a decode chunk's positions are each row's start + arange(S)
         q_start = positions[:, 0] if getattr(positions, "ndim", 1) == 2 else None
-        o = pin(attention_decode(q, kc, vc, new_len, q_start=q_start,
-                                 impl=attn_impl, block=block))
-        out = pin(jnp.einsum("bhsd,hdm->bsm", o, p["wo"].astype(x.dtype)))
+        o = attention_decode(q, kc, vc, new_len, q_start=q_start,
+                             impl=attn_impl, block=block)
+        out = jnp.einsum("bhsd,hdm->bsm", o, p["wo"].astype(x.dtype))
         return shard_act(out, "hidden"), new_cache
     if _ring_applicable(recipe, q, k):
         o = ring_attention_seq(

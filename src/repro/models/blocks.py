@@ -14,7 +14,6 @@ import jax
 import jax.numpy as jnp
 
 from .module import pspec
-from .numerics import pin
 from . import attention as attn
 from . import ffn as ffn_mod
 from . import ssm as ssm_mod
@@ -57,23 +56,23 @@ def attn_block(p, x, cfg, *, cache=None, positions=None, new_counts=None, prefil
     counts; whole-prompt prefill chunk), ``layer`` the layer's index into a
     stacked cache and ``slot`` the cache slot of the chunk's first row."""
     h, new_cache = attn.gqa_attention(
-        p["attn"], pin(rmsnorm(p["ln1"], x)),
+        p["attn"], rmsnorm(p["ln1"], x),
         n_heads=cfg.n_heads, n_kv=cfg.n_kv, head_dim=cfg.head_dim,
         rope_theta=cfg.rope_theta, positions=positions, cache=cache,
         attn_impl=cfg.attn_impl, block=cfg.attn_block, attn_mixed=cfg.attn_mixed,
         new_counts=new_counts, prefill=prefill, layer=layer, slot=slot,
     )
-    x = pin(x + h)
+    x = x + h
     aux = jnp.zeros((), jnp.float32)
     if cfg.ffn_kind == "moe":
         f, aux = ffn_mod.moe_ffn(p["ffn"], rmsnorm(p["ln2"], x), n_experts=cfg.n_experts,
                                  top_k=cfg.moe_top_k, capacity_factor=cfg.moe_capacity_factor,
                                  groups=cfg.moe_groups, dispatch=cfg.moe_dispatch)
     elif cfg.ffn_kind == "gelu":
-        f = ffn_mod.gelu_mlp(p["ffn"], pin(rmsnorm(p["ln2"], x)))
+        f = ffn_mod.gelu_mlp(p["ffn"], rmsnorm(p["ln2"], x))
     else:
-        f = ffn_mod.swiglu(p["ffn"], pin(rmsnorm(p["ln2"], x)))
-    return pin(x + f), new_cache, aux
+        f = ffn_mod.swiglu(p["ffn"], rmsnorm(p["ln2"], x))
+    return x + f, new_cache, aux
 
 
 # -------------------------------------------------------------- MLA block ----

@@ -66,7 +66,6 @@ from repro.core.plan import dispatch as dispatch_plan, intent_of
 from repro.core.traverser import traverser
 
 from .module import pspec
-from .numerics import pin
 from .sharding import current_recipe, ragged_expert_extents, shard_act
 
 # ----------------------------------------------------------------- dense ----
@@ -80,10 +79,10 @@ def swiglu_specs(d_model: int, d_ff: int, dtype=jnp.float32):
 
 
 def swiglu(p, x):
-    g = shard_act(pin(jnp.einsum("bsm,mf->bsf", x, p["w_gate"].astype(x.dtype))), "ffn_h")
-    u = shard_act(pin(jnp.einsum("bsm,mf->bsf", x, p["w_up"].astype(x.dtype))), "ffn_h")
-    h = pin(jax.nn.silu(g) * u)
-    return shard_act(pin(jnp.einsum("bsf,fm->bsm", h, p["w_down"].astype(x.dtype))), "hidden")
+    g = shard_act(jnp.einsum("bsm,mf->bsf", x, p["w_gate"].astype(x.dtype)), "ffn_h")
+    u = shard_act(jnp.einsum("bsm,mf->bsf", x, p["w_up"].astype(x.dtype)), "ffn_h")
+    h = jax.nn.silu(g) * u
+    return shard_act(jnp.einsum("bsf,fm->bsm", h, p["w_down"].astype(x.dtype)), "hidden")
 
 
 def gelu_mlp_specs(d_model: int, d_ff: int, dtype=jnp.float32):
@@ -96,9 +95,9 @@ def gelu_mlp_specs(d_model: int, d_ff: int, dtype=jnp.float32):
 
 
 def gelu_mlp(p, x):
-    h = shard_act(pin(jnp.einsum("bsm,mf->bsf", x, p["w_in"].astype(x.dtype))) + p["b_in"].astype(x.dtype), "ffn_h")
-    h = pin(jax.nn.gelu(h))
-    return shard_act(pin(pin(jnp.einsum("bsf,fm->bsm", h, p["w_out"].astype(x.dtype))) + p["b_out"].astype(x.dtype)), "hidden")
+    h = shard_act(jnp.einsum("bsm,mf->bsf", x, p["w_in"].astype(x.dtype)) + p["b_in"].astype(x.dtype), "ffn_h")
+    h = jax.nn.gelu(h)
+    return shard_act(jnp.einsum("bsf,fm->bsm", h, p["w_out"].astype(x.dtype)) + p["b_out"].astype(x.dtype), "hidden")
 
 
 # ------------------------------------------------------------------- MoE ----

@@ -22,7 +22,6 @@ from . import attention as attn_mod
 from . import blocks as blk
 from . import ssm as ssm_mod
 from .module import pspec, stack_specs, init_params, abstract_params, tree_size
-from .numerics import pin
 from .sharding import shard_act
 
 # ================================================================= specs ====
@@ -98,17 +97,17 @@ def embed_inputs(params, batch, cfg, *, positions=None):
         S = x.shape[1]
         pos = positions if positions is not None else jnp.arange(S)
         pe = _sinusoidal(pos, cfg.d_model).astype(cfg.act_dtype)
-        x = pin(x + (pe if pe.ndim == 3 else pe[None]))
+        x = x + (pe if pe.ndim == 3 else pe[None])
         return shard_act(x, "hidden")
     tokens = shard_act(batch["tokens"], "tokens")
-    x = pin(params["embed"].astype(cfg.act_dtype)[tokens])
+    x = params["embed"].astype(cfg.act_dtype)[tokens]
     return shard_act(x, "hidden")
 
 
 def lm_logits(params, x, cfg):
-    x = pin(blk.rmsnorm(params["final_norm"], x))
+    x = blk.rmsnorm(params["final_norm"], x)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = pin(jnp.einsum("bsm,mv->bsv", x, head.astype(x.dtype)))
+    logits = jnp.einsum("bsm,mv->bsv", x, head.astype(x.dtype))
     return shard_act(logits, "logits")
 
 

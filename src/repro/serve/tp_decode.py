@@ -27,7 +27,7 @@ step kinds (:func:`place_tp`) and no device holds the whole model.
 
 Scope: the attention families with plain GQA blocks (``dense``/``audio``),
 with or without QKV biases (bias shards ride the head/KV-group shards and
-are added between each projection and rope, the oracle's pinned order);
+are added between each projection and rope, as in the single-host block);
 heads, KV groups, FFN hidden and vocab must divide the ``model`` axis, batch
 slots must divide ``data`` x ``microbatches``.  MoE blocks are the one
 remaining exclusion — the engine falls back to the single-host path.
@@ -42,7 +42,6 @@ from repro.core.plan import intent_of, stagger
 from repro.models import lm
 from repro.models.attention import KVCache, apply_rope, attention_decode, rope_angles
 from repro.models.blocks import rmsnorm
-from repro.models.numerics import pin as _pin, pinned_rounding
 
 __all__ = ["make_tp_decode_step", "tp_decode_specs", "place_tp", "DECODE_TP_PLAN_INTENT"]
 
@@ -132,7 +131,7 @@ def place_tp(cfg, mesh, params, state):
 
 
 def make_tp_decode_step(cfg, mesh, *, slots: int, microbatches: int = 2,
-                        double_buffer: bool = True, attn_impl: str | None = None):
+                        attn_impl: str | None = None):
     """Build ``step(params, state, batch, counts) -> (logits, new_state)``.
 
     ``state`` is the stacked :class:`repro.models.lm.DecodeState`;
@@ -148,14 +147,9 @@ def make_tp_decode_step(cfg, mesh, *, slots: int, microbatches: int = 2,
     plan (see ``models/attention.py``'s dispatch table): ``"pallas"`` /
     ``"interpret"`` run the flash-decoding kernel inside each
     microbatch's compute stage, ``None`` resolves per backend, ``"jnp"``
-    keeps the dense pinned jnp path (the token-equality oracle's form).
+    keeps the dense jnp path.
     """
     _check(cfg, mesh, slots, microbatches)
-    # This body traces under pinned rounding (models/numerics.py): every
-    # activation-dtype boundary carries a barrier so XLA cannot fold the
-    # round into downstream f32 internals.  The oracle decode jit pins the
-    # same boundaries, which is what makes the distributed engine's greedy
-    # tokens match the single-host oracle's token-for-token.
     msize = mesh.shape["model"]
     dsize = mesh.shape["data"]
     mb = microbatches
@@ -185,10 +179,10 @@ def make_tp_decode_step(cfg, mesh, *, slots: int, microbatches: int = 2,
             e = jnp.where(ok[..., None], e, jnp.zeros((), act_dt))
             # each token's row lives on exactly one rank: the psum is a pure
             # routing gather (one nonzero addend) — bitwise the oracle lookup
-            x = _pin(shard_all_reduce_start(e, "model").wait())
+            x = shard_all_reduce_start(e, "model").wait()
         else:
-            x = _pin(inputs.astype(act_dt)
-                     + lm._sinusoidal(pos2d, cfg.d_model).astype(act_dt))
+            x = (inputs.astype(act_dt)
+                 + lm._sinusoidal(pos2d, cfg.d_model).astype(act_dt))
 
         rows = [slice(s * bm, (s + 1) * bm) for s in range(mb)]
         xs = [x[r] for r in rows]
@@ -231,34 +225,34 @@ def make_tp_decode_step(cfg, mesh, *, slots: int, microbatches: int = 2,
             def attn_compute(_c, _s, s, l=l, ln1=ln1, wq=wq, wk=wk, wv=wv, wo=wo,
                              bq=bq, bk=bk, bv=bv, new_k_l=new_k_l, new_v_l=new_v_l):
                 xi = xs[s]
-                xn = _pin(rmsnorm(ln1, xi))
-                q = _pin(jnp.einsum("bsm,mhd->bhsd", xn, wq.astype(xi.dtype)))
-                k = _pin(jnp.einsum("bsm,mgd->bgsd", xn, wk.astype(xi.dtype)))
-                v = _pin(jnp.einsum("bsm,mgd->bgsd", xn, wv.astype(xi.dtype)))
+                xn = rmsnorm(ln1, xi)
+                q = jnp.einsum("bsm,mhd->bhsd", xn, wq.astype(xi.dtype))
+                k = jnp.einsum("bsm,mgd->bgsd", xn, wk.astype(xi.dtype))
+                v = jnp.einsum("bsm,mgd->bgsd", xn, wv.astype(xi.dtype))
                 if bq is not None:
                     # local head/group shard of the bias, added between the
-                    # projection and rope — the oracle's pinned order
-                    # (models/attention.py gqa_attention)
-                    q = _pin(q + bq.astype(xi.dtype)[None, :, None, :])
-                    k = _pin(k + bk.astype(xi.dtype)[None, :, None, :])
-                    v = _pin(v + bv.astype(xi.dtype)[None, :, None, :])
+                    # projection and rope, as in models/attention.py's
+                    # gqa_attention
+                    q = q + bq.astype(xi.dtype)[None, :, None, :]
+                    k = k + bk.astype(xi.dtype)[None, :, None, :]
+                    v = v + bv.astype(xi.dtype)[None, :, None, :]
                 cos, sin = rope_angles(p_mb[s], cfg.head_dim, cfg.rope_theta)
-                q = _pin(apply_rope(q, cos, sin))
-                k = _pin(apply_rope(k, cos, sin))
+                q = apply_rope(q, cos, sin)
+                k = apply_rope(k, cos, sin)
                 length = length_all[l][rows[s]]
                 nk = masked_update(k_all[l][rows[s]], k, length, a_mb[s])
                 nv = masked_update(v_all[l][rows[s]], v, length, a_mb[s])
                 new_k_l[s] = nk
                 new_v_l[s] = nv
-                o = _pin(attention_decode(q, nk, nv, length + c_mb[s],
-                                          q_start=st_mb[s], impl=attn_impl))
+                o = attention_decode(q, nk, nv, length + c_mb[s],
+                                     q_start=st_mb[s], impl=attn_impl)
                 # local head shard's partial projection — the transfer stage
                 # issues its Iallreduce; the next microbatch's math hides it.
                 # Partials stay f32 through the reduction and are rounded to
                 # the activation dtype once, post-psum: splitting the dot
                 # across ranks then only perturbs f32-level accumulation
-                # order, so the reduced sum rounds to the same low-precision
-                # value as the oracle's single full-contraction dot.  (The
+                # order ahead of the one rounding the single-host dot has.
+                # (The
                 # TPU compiler folds that convert into a bf16-typed
                 # all-reduce that still sums in f32: on a v5e its result
                 # equals the exact sum rounded once, element for element.)
@@ -269,9 +263,9 @@ def make_tp_decode_step(cfg, mesh, *, slots: int, microbatches: int = 2,
                 mb,
                 transfer=lambda part, s: shard_all_reduce_start(part, "model"),
                 compute=attn_compute,
-                epilogue=lambda done, _s: [_pin(d.astype(act_dt)) for d in done],
-            ).run(None, None, double_buffer=double_buffer, after=last)
-            xs = [_pin(xs[s] + attn_done[s]) for s in range(mb)]
+                epilogue=lambda done, _s: [d.astype(act_dt) for d in done],
+            ).run(None, None, double_buffer=True, after=last)
+            xs = [xs[s] + attn_done[s] for s in range(mb)]
 
             ffn = blocks["ffn"]
             if cfg.ffn_kind == "gelu":
@@ -281,16 +275,16 @@ def make_tp_decode_step(cfg, mesh, *, slots: int, microbatches: int = 2,
                 b_out = ffn["b_out"][l]
 
                 def ffn_compute(_c, _s, s, ln2=ln2, w_in=w_in, w_out=w_out, b_in=b_in):
-                    xn = _pin(rmsnorm(ln2, xs[s]))
-                    h = _pin(jnp.einsum("bsm,mf->bsf", xn, w_in.astype(xn.dtype)))
-                    h = _pin(jax.nn.gelu(h + b_in.astype(xn.dtype)))
+                    xn = rmsnorm(ln2, xs[s])
+                    h = jnp.einsum("bsm,mf->bsf", xn, w_in.astype(xn.dtype))
+                    h = jax.nn.gelu(h + b_in.astype(xn.dtype))
                     return jnp.einsum("bsf,fm->bsm", h, w_out.astype(xn.dtype),
                                       preferred_element_type=jnp.float32)
 
                 def ffn_epilogue(done, _s, b_out=b_out):
                     # round the f32-reduced sum once, then add the replicated
                     # output bias in the activation dtype — the oracle's order
-                    return [_pin(_pin(d.astype(act_dt)) + b_out.astype(act_dt))
+                    return [d.astype(act_dt) + b_out.astype(act_dt)
                             for d in done]
             else:
                 w_gate = ffn["w_gate"][l]
@@ -298,23 +292,23 @@ def make_tp_decode_step(cfg, mesh, *, slots: int, microbatches: int = 2,
                 w_down = ffn["w_down"][l]
 
                 def ffn_compute(_c, _s, s, ln2=ln2, w_gate=w_gate, w_up=w_up, w_down=w_down):
-                    xn = _pin(rmsnorm(ln2, xs[s]))
-                    g = _pin(jnp.einsum("bsm,mf->bsf", xn, w_gate.astype(xn.dtype)))
-                    u = _pin(jnp.einsum("bsm,mf->bsf", xn, w_up.astype(xn.dtype)))
-                    h = _pin(jax.nn.silu(g) * u)
+                    xn = rmsnorm(ln2, xs[s])
+                    g = jnp.einsum("bsm,mf->bsf", xn, w_gate.astype(xn.dtype))
+                    u = jnp.einsum("bsm,mf->bsf", xn, w_up.astype(xn.dtype))
+                    h = jax.nn.silu(g) * u
                     return jnp.einsum("bsf,fm->bsm", h, w_down.astype(xn.dtype),
                                       preferred_element_type=jnp.float32)
 
                 def ffn_epilogue(done, _s):
-                    return [_pin(d.astype(act_dt)) for d in done]
+                    return [d.astype(act_dt) for d in done]
 
             ffn_done = stagger(
                 mb,
                 transfer=lambda part, s: shard_all_reduce_start(part, "model"),
                 compute=ffn_compute,
                 epilogue=ffn_epilogue,
-            ).run(None, None, double_buffer=double_buffer, after=attn_done[-1])
-            xs = [_pin(xs[s] + ffn_done[s]) for s in range(mb)]
+            ).run(None, None, double_buffer=True, after=attn_done[-1])
+            xs = [xs[s] + ffn_done[s] for s in range(mb)]
             last = ffn_done[-1]
 
             new_k_layers.append(jnp.concatenate(new_k_l, axis=0))
@@ -323,11 +317,11 @@ def make_tp_decode_step(cfg, mesh, *, slots: int, microbatches: int = 2,
         x = jnp.concatenate(xs, axis=0)
         # only each row's last valid token predicts the next one
         x = x[jnp.arange(Bl), jnp.maximum(counts - 1, 0)][:, None]
-        xn = _pin(rmsnorm(params["final_norm"], x))
+        xn = rmsnorm(params["final_norm"], x)
         head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
         # vocab-sharded head: the contraction dim is replicated, so each
-        # rank's logit columns are full dots — pinned like lm_logits'
-        logits_loc = _pin(jnp.einsum("bsm,mv->bsv", xn, head.astype(xn.dtype)))
+        # rank's logit columns are full dots, as in lm_logits
+        logits_loc = jnp.einsum("bsm,mv->bsv", xn, head.astype(xn.dtype))
         # terminal Iallgather of the local vocab shards (rank-ordered)
         logits = shard_all_gather_start(logits_loc, "model", axis=2).wait()
 
@@ -347,11 +341,10 @@ def make_tp_decode_step(cfg, mesh, *, slots: int, microbatches: int = 2,
     def step(params, state, batch, counts):
         caches = state.caches
         inputs = batch["tokens"] if tokens_in else batch["embeds"]
-        with pinned_rounding():
-            logits, nk, nv, nlen, npos = fn(
-                params, caches.k, caches.v, caches.length, state.positions,
-                inputs, counts.astype(jnp.int32),
-            )
+        logits, nk, nv, nlen, npos = fn(
+            params, caches.k, caches.v, caches.length, state.positions,
+            inputs, counts.astype(jnp.int32),
+        )
         new_state = lm.DecodeState(caches=KVCache(nk, nv, nlen), positions=npos)
         return logits, new_state
 

@@ -283,11 +283,3 @@ def make_eval_step(cfg, recipe):
 
     return eval_step
 
-
-def make_serve_step(cfg, recipe):
-    def serve_step(params, state, batch):
-        with use_recipe(recipe):
-            logits, new_state = lm.decode_step(params, state, batch, cfg)
-        return logits, new_state
-
-    return serve_step

@@ -143,6 +143,26 @@ def test_step_programs_do_not_copy_the_cache(arch, program):
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_step_programs_hold_no_rounding_barrier(program):
+    """The served step programs are the model's math as training and the
+    float32 reference trace it: the decode step (B rows, S=1) and the
+    one-row prefill (into its slot) lower with no optimization barrier, so
+    XLA fuses across the activation-dtype casts as it does everywhere else."""
+    B, T = 8, 128
+    cfg = configs.get("phi4-mini-3.8b", smoke=True)
+    params = jax.eval_shape(lambda: lm.init_model(cfg, jax.random.PRNGKey(0)))
+    eng, cfg = _engine("phi4-mini-3.8b", params, B, T)
+    state = jax.eval_shape(lambda: eng.state)
+    if program == "decode":
+        fn, rows, S, slot = eng.decode_fn, B, 1, ()
+    else:
+        fn, rows, S, slot = eng.prefill_fn, 1, 64, (jax.ShapeDtypeStruct((), jnp.int32),)
+    counts = jax.ShapeDtypeStruct((rows,), jnp.int32)
+    text = fn.lower(params, state, _step_args(cfg, rows, S, abstract=True), counts, *slot).as_text()
+    assert "optimization_barrier" not in text
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
 @pytest.mark.parametrize("arch", ["phi4-mini-3.8b", "minicpm3-4b", "zamba2-7b"])
 def test_inactive_rows_keep_their_cache(arch, program):
     """Rows with count 0 keep every cache byte through a step, also where a

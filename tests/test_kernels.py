@@ -347,9 +347,9 @@ def test_flash_decode_chunk_row_blocks():
 
 
 def test_flash_decode_vs_model_decode_tolerance():
-    """The kernel path agrees with the model-facing pinned jnp decode within
-    pinned-rounding tolerance (the jnp path rounds normalized probabilities
-    to the cache dtype; the kernel rounds the unnormalized tile)."""
+    """The kernel path agrees with the model-facing jnp decode within bf16
+    rounding tolerance (the jnp path rounds normalized probabilities to the
+    cache dtype; the kernel rounds the unnormalized tile)."""
     from repro.models.attention import attention_decode
 
     B, H, G, T, D = 2, 4, 2, 64, 16

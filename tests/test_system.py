@@ -10,15 +10,6 @@ import jax
 import jax.numpy as jnp
 
 from repro import configs
-from repro.configs import SHAPES
-
-
-def test_shape_cells_cover_assignment():
-    assert set(SHAPES) == {"train_4k", "prefill_32k", "decode_32k", "long_500k"}
-    assert SHAPES["train_4k"].seq_len == 4096 and SHAPES["train_4k"].global_batch == 256
-    assert SHAPES["prefill_32k"].seq_len == 32768 and SHAPES["prefill_32k"].global_batch == 32
-    assert SHAPES["decode_32k"].global_batch == 128
-    assert SHAPES["long_500k"].seq_len == 524288 and SHAPES["long_500k"].global_batch == 1
 
 
 def test_all_ten_archs_registered():

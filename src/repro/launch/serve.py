@@ -18,7 +18,8 @@ open DIR in TensorBoard or Perfetto to see the engine's phase spans
 (``engine.admit``, ``engine.prefill_launch``, ``engine.decode_launch``,
 ``engine.fetch``, ``engine.sample``) and each request's ``engine.queued``
 span, with their arguments (admitted and queued requests, KV bytes in use,
-rows per step, prefill bucket), on the device events' clock.
+rows per step, prefill bucket, bytes fetched a step), on the device events'
+clock.
 """
 import argparse
 import contextlib

@@ -42,10 +42,12 @@ iteration of :meth:`Engine.run`:
     bucket, and idle slots where a call runs every slot);
   * ``engine.decode_launch`` (``rows``): the decode feed built, copied and
     the decode step dispatched;
-  * ``engine.fetch``: the logits sliced and pulled to the host, which waits
-    here for the decode program;
-  * ``engine.sample`` (``rows``): next tokens chosen, ledger advanced,
-    finished slots released.
+  * ``engine.fetch`` (``bytes``): the picking program launched on the
+    decode program's logits and the next token ids it returns pulled to the
+    host, which waits here for both programs; ``bytes`` is what the copy
+    moved;
+  * ``engine.sample`` (``rows``): the picked tokens appended, ledger
+    advanced, finished slots released.
 
 Besides, ``engine.queued`` (``rid``) runs per request from :meth:`submit` to
 its admission.
@@ -204,6 +206,27 @@ class Engine:
                 donate_argnums=1)
             self.decode_fn = jax.jit(gspmd_step, donate_argnums=1)
 
+        vocab, temperature = cfg.vocab, scfg.temperature
+
+        def _pick_tokens(logits, key):
+            """The next token of every row from a step program's logits: the
+            argmax of the last position over the real vocabulary (the padded
+            columns are never picked; ties go to the first index) or, at
+            ``temperature`` > 0, a draw from its softmax.  Returns the (B,)
+            int32 ids and the key for the next draw."""
+            last = logits[:, -1, :vocab]
+            if temperature > 0:
+                key, sub = jax.random.split(key)
+                ids = jax.random.categorical(sub, last.astype(jnp.float32) / temperature)
+            else:
+                ids = jnp.argmax(last, axis=-1)
+            return ids.astype(jnp.int32), key
+
+        # a program of its own, launched right behind the decode program, so
+        # the host pulls B ids instead of B x vocab logits; its module,
+        # ``jit__pick_tokens``, is neither step program's
+        self.pick_fn = jax.jit(_pick_tokens)
+
     # ------------------------------------------------------------ public ----
     def submit(self, request_id: int, prompt: list[int] | None = None,
                max_new_tokens: int = 16, prompt_embeds=None) -> None:
@@ -350,19 +373,16 @@ class Engine:
             span.set_metadata(rows=rows)
             batch, counts = jax.device_put(self._feed(feeds, B, 1))
             logits, self.state = self.decode_fn(self.params, self.state, batch, counts)
-        with TraceAnnotation("engine.fetch"):
-            logits = np.asarray(logits[:, -1, : self.cfg.vocab])  # strip padded vocab
+        with TraceAnnotation("engine.fetch") as span:
+            ids, self._key = self.pick_fn(logits, self._key)
+            ids = np.asarray(ids)
+            span.set_metadata(bytes=ids.nbytes)
         with TraceAnnotation("engine.sample", rows=rows):
             for i, slot in enumerate(self.slots):
                 if slot.request_id is None:
                     continue
                 self.ledger.advance(i, 1)
-                if self.scfg.temperature > 0:
-                    self._key, sub = jax.random.split(self._key)
-                    probs = jax.nn.softmax(jnp.asarray(logits[i]) / self.scfg.temperature)
-                    nxt = int(jax.random.categorical(sub, jnp.log(probs + 1e-9)))
-                else:
-                    nxt = int(np.argmax(logits[i]))
+                nxt = int(ids[i])
                 slot.tokens.append(nxt)
                 if self._embeds_in:
                     slot.next_embed = self._featurize([nxt])[0]

@@ -20,12 +20,13 @@ PHASES = ("engine.admit", "engine.prefill_launch", "engine.decode_launch",
 # together, 12 waits in the queue until 10 finishes after 3 decode steps
 REQUESTS = [(10, 5, 3), (11, 9, 5), (12, 4, 2)]
 DECODE_STEPS = 5
+SLOTS = 2
 
 
 def _serve(params, cfg, trace_dir=None):
     """Serve ``REQUESTS`` on a fresh engine, under the profiler if
     ``trace_dir`` is given; returns the finished map and the step calls."""
-    eng = Engine(cfg, params, ServeConfig(max_len=64, batch_slots=2, eos_token=-1))
+    eng = Engine(cfg, params, ServeConfig(max_len=64, batch_slots=SLOTS, eos_token=-1))
     calls = defaultdict(int)
 
     def counted(kind, fn):
@@ -125,6 +126,12 @@ def test_span_args(served):
     assert [(s[3]["calls"], s[3]["padded_tokens"]) for s in prefills] == [(2, 4), (1, 1)]
     assert [s[3]["rows"] for s in named(served, "engine.decode_launch")] == [2] * DECODE_STEPS
     assert [s[3]["rows"] for s in named(served, "engine.sample")] == [2] * DECODE_STEPS
+
+
+def test_fetch_pulls_only_the_picked_ids(served):
+    """The host pulls one int32 id per slot a decode step, not the logits."""
+    fetches = named(served, "engine.fetch")
+    assert [s[3]["bytes"] for s in fetches] == [4 * SLOTS] * DECODE_STEPS
 
 
 def test_phase_spans_tile_the_loop_in_order(served):
